@@ -77,13 +77,8 @@ def _ring_hlo_bytes(k: int, d: int, scale: float) -> tuple[int, int]:
                                     lambda s, dst, m: s * m[:, None])
             return h[None]
 
-        shard_map = (jax.shard_map if hasattr(jax, "shard_map")
-                     else __import__("jax.experimental.shard_map",
-                                     fromlist=["shard_map"]).shard_map)
-        kw = ({{"check_vma": False}} if hasattr(jax, "shard_map")
-              else {{"check_rep": False}})
-        fn = shard_map(per_device, mesh=mesh, in_specs=(P("parts"),),
-                       out_specs=P("parts"), **kw)
+        fn = jax.shard_map(per_device, mesh=mesh, in_specs=(P("parts"),),
+                           out_specs=P("parts"), check_vma=False)
         hlo = jax.jit(fn).lower(blocks).compile().as_text()
         coll = collective_bytes_from_hlo(hlo)
         print(coll["count_per_kind"].get("collective-permute", 0),
@@ -95,7 +90,7 @@ def _ring_hlo_bytes(k: int, d: int, scale: float) -> tuple[int, int]:
         env={"XLA_FLAGS": f"--xla_force_host_platform_device_count={k}",
              "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo",
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     if proc.returncode != 0:
         raise RuntimeError(proc.stderr[-2000:])

@@ -25,7 +25,10 @@ from benchmarks.common import emit
 # `python benchmarks/roofline.py --smoke` is CI-fast without env setup
 AGG_SCALE = float(os.environ.get("BENCH_SCALE", "0.02"))
 
-RESULTS = os.environ.get("DRYRUN_RESULTS", "/root/repo/dryrun_results.json")
+RESULTS = os.environ.get(
+    "DRYRUN_RESULTS",
+    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                 "dryrun_results.json"))
 
 
 def _agg_traffic_bytes(book, spec, backend) -> str:

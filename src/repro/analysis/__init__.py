@@ -9,7 +9,7 @@ on any error-level finding. The pieces:
                 kind under the output-shape convention, replica groups,
                 scatter/convert inventory, input_output_alias)
   jaxpr.py      recursive jaxpr walking (primitive census, narrowing
-                converts) across cond/scan/pjit/pallas_call sub-jaxprs
+                converts) across cond/scan/jit/pallas_call sub-jaxprs
   programs.py   the analyzed-program grid + seeded violations
   rules.py      the rule registry (no-scatter, dtype-policy,
                 collective-budget, donation, retrace-guard) and Report

@@ -3,7 +3,7 @@
 A jaxpr is what `jax.jit` will compile — walking it catches regressions
 BEFORE any (slow) XLA compile: a scatter primitive sneaking onto the tiled
 hot path, a narrowing `convert_element_type` appearing on an fp32-default
-path. The walker recurses into every sub-jaxpr (cond/scan/pjit/custom_vjp
+path. The walker recurses into every sub-jaxpr (cond/scan/jit/custom_vjp
 bodies, `pallas_call` kernels), generalising the ad-hoc helper the
 acceptance tests in `tests/test_aggregate.py` used to carry inline.
 """
@@ -24,11 +24,11 @@ __all__ = [
 
 
 def _subjaxprs(value) -> Iterator:
-    import jax.core as core
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    if isinstance(value, core.ClosedJaxpr):
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:
@@ -40,8 +40,8 @@ def _subjaxprs(value) -> Iterator:
 
 def iter_eqns(jaxpr) -> Iterator:
     """Every equation in a (Closed)Jaxpr, recursing into sub-jaxprs
-    (cond/scan/pjit/custom_vjp/pallas_call bodies)."""
-    j = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
+    (cond/scan/jit/custom_vjp/pallas_call bodies)."""
+    j = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in j.eqns:
         yield eqn
         for v in eqn.params.values():

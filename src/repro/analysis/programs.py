@@ -205,18 +205,6 @@ def _inference_jaxprs(model: str, backend: str, k: int = K) -> list:
 # ---------------------------------------------------------------------------
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, check_vma=False,
-                             in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, check_rep=False,
-                     in_specs=in_specs, out_specs=out_specs)
-
-
 @functools.lru_cache(maxsize=1)
 def _ring_fixture():
     from repro.core.partition_book import build_blockrow_book
@@ -257,7 +245,8 @@ def _ring_hlo(codec: Optional[str]) -> str:
         h = sync.edge_aggregate(blk, blk.x, lambda s, dst, m: s * m[:, None])
         return h[None]
 
-    fn = _shard_map(per_device, mesh, (P("parts"),), P("parts"))
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=(P("parts"),),
+                       out_specs=P("parts"), check_vma=False)
     return jax.jit(fn).lower(blocks).compile().as_text()
 
 
@@ -277,7 +266,8 @@ def _partial_agg_hlo(mode: str, codec: Optional[str]) -> str:
         h = sync.broadcast(sync.reduce_sum(blk.x))   # one reduce+broadcast
         return jax.tree.map(lambda a: a[None], h)
 
-    fn = _shard_map(per_device, mesh, (P("parts"),), P("parts"))
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=(P("parts"),),
+                       out_specs=P("parts"), check_vma=False)
     return jax.jit(fn).lower(blocks).compile().as_text()
 
 

@@ -177,14 +177,9 @@ def wrap_spmd(fn, k: int, mode: str,
         out = fn(params, *args)
         return jax.tree.map(lambda a: a[None], out)
 
-    specs = dict(in_specs=(P(),) + (P(AXIS),) * n_mapped, out_specs=P(AXIS))
-    # jax >= 0.6 exposes jax.shard_map (check_vma); 0.4.x has the
-    # experimental module (check_rep). Same semantics either way.
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(per_device, mesh=mesh, check_vma=False, **specs)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(per_device, mesh=mesh, check_rep=False, **specs)
+    return jax.shard_map(per_device, mesh=mesh, check_vma=False,
+                         in_specs=(P(),) + (P(AXIS),) * n_mapped,
+                         out_specs=P(AXIS))
 
 
 # ---------------------------------------------------------------------------
@@ -231,9 +226,17 @@ class FullBatchTrainer:
         )
         blocks = build_device_blocks(book, features, labels, train_mask)
         params = models.init_params(spec, seed=seed)
+        opt_state = adam_init(params)
+        if mode == "shard_map" and k > 1:
+            # each device holds its own partition of the stacked blocks and
+            # a replica of the model, as the step's outputs will
+            P = jax.sharding.PartitionSpec
+            shard = functools.partial(jax.sharding.NamedSharding, mesh)
+            blocks = jax.device_put(blocks, shard(P(AXIS)))
+            params, opt_state = jax.device_put((params, opt_state), shard(P()))
         return cls(
             spec=spec, book=book, blocks=blocks, sync_mode=sync_mode,
-            mode=mode, mesh=mesh, params=params, opt_state=adam_init(params),
+            mode=mode, mesh=mesh, params=params, opt_state=opt_state,
             lr=lr, codec=codec,
         )
 

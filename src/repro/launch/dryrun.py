@@ -33,11 +33,10 @@ from repro.dist.sharding import ShardingPolicy
 from repro.launch.hlo import collective_bytes_from_hlo  # noqa: F401 (re-export)
 from repro.launch.mesh import TPU_V5E, make_production_mesh
 
-RESULTS_PATH = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                            "dryrun_results.json")
-RESULTS_PATH = os.path.abspath(
-    os.environ.get("DRYRUN_RESULTS", "/root/repo/dryrun_results.json")
-)
+RESULTS_PATH = os.path.abspath(os.environ.get(
+    "DRYRUN_RESULTS",
+    os.path.join(os.path.dirname(__file__), "..", "..", "..",
+                 "dryrun_results.json")))
 
 def model_flops(cfg, shape) -> float:
     """6*N*D (dense) / 6*N_active*D (MoE) for train; 2*N*D for inference."""

@@ -32,10 +32,12 @@ from repro.gnn.inference import (
     edge_assignment_from_vertex,
 )
 from repro.gnn.models import GNNSpec, init_params
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import build_serving, run_serving_sim
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="OR", choices=["HO", "DI", "EN", "EU", "OR"])
     ap.add_argument("--scale", type=float, default=0.05)

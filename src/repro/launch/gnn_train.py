@@ -30,6 +30,7 @@ from repro.gnn.feature_store import CACHE_POLICIES
 from repro.gnn.fullbatch import FullBatchTrainer
 from repro.gnn.minibatch import MiniBatchTrainer
 from repro.gnn.models import GNNSpec
+from repro.launch.compile_cache import use_compile_cache
 
 CRASH_EXIT = 3  # injected worker crash (distinct from real failures)
 
@@ -51,7 +52,8 @@ def _mark_corrupt_handled(plan) -> None:
             plan.mark_handled(ev)
 
 
-def main() -> None:
+def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="OR", choices=["HO", "DI", "EN", "EU", "OR"])
     ap.add_argument("--scale", type=float, default=0.05)
@@ -131,7 +133,7 @@ def main() -> None:
                          "straggler@step:1,delay:0.05, corrupt-ckpt. "
                          f"Kinds: {', '.join(FAULT_KINDS)}")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     plan, injector = None, None
     if args.inject_fault:

@@ -11,25 +11,18 @@ gradient reduction crosses it; `model` stays inside a pod (ICI).
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    # jax >= 0.5 wants explicit axis_types; jax 0.4.x has neither the
-    # parameter nor jax.sharding.AxisType. Auto is the 0.4.x behavior.
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh (tests use small ones, e.g. (2, 2))."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants (per chip) — used by the roofline analysis.

@@ -8,6 +8,7 @@ rule/CLI tests run the real grid programs and the seeded violations.
 """
 
 import json
+import os
 import subprocess
 import sys
 
@@ -36,6 +37,8 @@ from repro.analysis.deadcode import (
     dead_exports,
     reference_counts,
 )
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -153,7 +156,7 @@ def test_donation_probe_aliases_on_cpu():
 
 
 def test_iter_eqns_recurses_into_subjaxprs():
-    """Primitives inside scan/pjit bodies are visible to the walker."""
+    """Primitives inside scan/jit bodies are visible to the walker."""
 
     def body(c, _):
         return jnp.sin(c) * 2.0, None
@@ -165,7 +168,7 @@ def test_iter_eqns_recurses_into_subjaxprs():
 
     cj = jax.make_jaxpr(fn)(jnp.ones(4))
     names = primitive_names(cj)
-    assert {"sin", "cos", "scan", "pjit"} <= names
+    assert {"sin", "cos", "scan", "jit"} <= names
     counts = count_primitives(cj)
     assert counts["sin"] == 1 and counts["cos"] == 1
     assert len(list(iter_eqns(cj))) == sum(counts.values())
@@ -287,7 +290,7 @@ def test_reference_counts_are_token_matches(tmp_path):
 def test_repo_has_no_unannotated_dead_exports():
     """The advisory sweep stays clean on the repo itself — new dead exports
     must be deleted or `# lint: keep`-annotated."""
-    assert dead_exports("/root/repo") == []
+    assert dead_exports(REPO) == []
 
 
 # ---------------------------------------------------------------------------
@@ -300,7 +303,7 @@ def _lint(*argv):
         [sys.executable, "-m", "repro.launch.gnn_lint", *argv],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo",
+        cwd=REPO,
     )
 
 

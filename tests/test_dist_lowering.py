@@ -3,11 +3,14 @@ placeholder host devices so the main test process keeps 1 device."""
 
 import importlib.util
 import json
+import os
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The LM distribution layer (repro.dist: step builders, sharding policies,
 # analytic costs) is not part of every build of this repo; the GNN study
@@ -27,7 +30,7 @@ def _run(code: str, devices: int = 8) -> str:
              # TPU/GPU plugins before falling back to CPU
              "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo",
+        cwd=REPO,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     return proc.stdout
@@ -164,13 +167,9 @@ def test_segment_max_tiled_under_shard_map():
                                 local_dst=l[0], backend="tiled", reduce="max")
             return out[None]
 
-        shard_map = (jax.shard_map if hasattr(jax, "shard_map")
-                     else __import__("jax.experimental.shard_map",
-                                     fromlist=["shard_map"]).shard_map)
-        kw = ({"check_vma": False} if hasattr(jax, "shard_map")
-              else {"check_rep": False})
-        fn = shard_map(per_device, mesh=mesh, in_specs=(P("parts"),) * 4,
-                       out_specs=P("parts"), **kw)
+        fn = jax.shard_map(per_device, mesh=mesh,
+                           in_specs=(P("parts"),) * 4, out_specs=P("parts"),
+                           check_vma=False)
         got = jax.jit(fn)(jnp.asarray(msgs), jnp.asarray(dst),
                           jnp.asarray(order), jnp.asarray(ldst))
         expect = jax.vmap(lambda m, d: ops.aggregate(

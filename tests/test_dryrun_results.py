@@ -6,7 +6,8 @@ import os
 
 import pytest
 
-RESULTS = "/root/repo/dryrun_results.json"
+RESULTS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                       "dryrun_results.json")
 
 pytestmark = pytest.mark.skipif(
     not os.path.exists(RESULTS),

@@ -55,6 +55,30 @@ def test_segment_reduce_max_sweep(e, v, f, dtype):
 
 
 @pytest.mark.parametrize("combiner", ["sum", "max"])
+def test_segment_reduce_paper_width_multi_block(combiner):
+    """F=512 (4 feature tiles) with 3 edge blocks per row tile: each output
+    block is revisited across the reduction axis and must keep the partial
+    result of every earlier edge block."""
+    rng = np.random.default_rng(11)
+    v, f = 512, 512
+    e = 2 * 3 * ops.DEFAULT_BLOCK_E - 100   # 2 row tiles, 3 blocks each
+    dst = rng.integers(0, v, e).astype(np.int32)
+    msgs = rng.normal(size=(e, f)).astype(np.float32)
+    order, local_dst, rows_p = ops.prepare_tiled_edges(dst, v)
+    n_tiles = rows_p // ops.DEFAULT_TILE_V
+    assert order.shape[0] // n_tiles // ops.DEFAULT_BLOCK_E >= 2
+    assert f // ops._pick_tile_f(f) == 4
+    fill = 0.0 if combiner == "sum" else -np.inf
+    msgs_pad = np.concatenate([msgs, np.full((1, f), fill, np.float32)])[order]
+    ref_fn = ref.segment_sum_ref if combiner == "sum" else ref.segment_max_ref
+    expect = ref_fn(jnp.asarray(msgs), jnp.asarray(dst), v)
+    out = ops.segment_spmm(jnp.asarray(msgs_pad), jnp.asarray(local_dst),
+                           rows_p, combiner=combiner, interpret=True)
+    np.testing.assert_allclose(np.asarray(out[:v]), np.asarray(expect),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("combiner", ["sum", "max"])
 def test_segment_spmm_oracle_unpadded_num_rows(combiner):
     """Regression: the oracle path derived n_tiles by floor division and
     assumed divisibility, so a direct call with an UNPADDED num_rows

@@ -13,6 +13,7 @@ Three families of pins:
                tolerance of fp32 for sage/gcn/gat x halo/ring and mini-batch
 """
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -263,14 +264,10 @@ def test_int8_ef_grad_reduce_shard_map_matches_vmap():
 
         vfn = jax.jit(jax.vmap(reduce_lane, axis_name="parts"))
         mesh = jax.make_mesh((k,), ("parts",))
-        shard_map = (jax.shard_map if hasattr(jax, "shard_map")
-                     else __import__("jax.experimental.shard_map",
-                                     fromlist=["shard_map"]).shard_map)
-        kw = ({"check_vma": False} if hasattr(jax, "shard_map")
-              else {"check_rep": False})
-        sfn = jax.jit(shard_map(reduce_lane, mesh=mesh,
-                                in_specs=(P("parts"), P("parts")),
-                                out_specs=(P("parts"), P("parts")), **kw))
+        sfn = jax.jit(jax.shard_map(reduce_lane, mesh=mesh,
+                                    in_specs=(P("parts"), P("parts")),
+                                    out_specs=(P("parts"), P("parts")),
+                                    check_vma=False))
 
         ef_v, ef_s = ef_init(seq[0]), ef_init(seq[0])
         maxerr = 0.0
@@ -290,7 +287,7 @@ def test_int8_ef_grad_reduce_shard_map_matches_vmap():
         env={"XLA_FLAGS": "--xla_force_host_platform_device_count=4",
              "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo",
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "maxerr" in proc.stdout
