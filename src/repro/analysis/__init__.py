@@ -7,7 +7,8 @@ on any error-level finding. The pieces:
 
   hlo.py        text-level HLO analysis (collective payload bytes per op
                 kind under the output-shape convention, replica groups,
-                scatter/convert inventory, input_output_alias)
+                scatter/convert inventory, input_output_alias, each
+                instruction's op_name)
   jaxpr.py      recursive jaxpr walking (primitive census, narrowing
                 converts) across cond/scan/jit/pallas_call sub-jaxprs
   programs.py   the analyzed-program grid + seeded violations
@@ -20,6 +21,7 @@ from repro.analysis.hlo import (
     analyze_hlo,
     collective_bytes_from_hlo,
     input_output_aliases_from_hlo,
+    op_names_from_hlo,
 )
 from repro.analysis.jaxpr import (
     convert_ops,
@@ -45,6 +47,7 @@ __all__ = [
     "analyze_hlo",
     "collective_bytes_from_hlo",
     "input_output_aliases_from_hlo",
+    "op_names_from_hlo",
     "convert_ops",
     "count_primitives",
     "iter_eqns",

@@ -23,6 +23,12 @@ so the collective-byte accounting cannot drift between them.
                    module header, i.e. which donated arguments XLA
                    actually aliased (the donation rule's raw material).
 
+`op_names_from_hlo` maps each instruction to the JAX name stack in its
+metadata, which carries the program's `jax.named_scope`s (`edge.gather`,
+`edge.reorder`, `edge.reduce`) and marks backward ops `transpose(...)`: a
+profiler's device events name instructions, so the map attributes device
+time to the program's scopes.
+
 `collective_bytes_from_hlo` keeps its historical return shape
 ({bytes_per_kind, count_per_kind, total_bytes}) on top of the same walk.
 
@@ -44,6 +50,7 @@ __all__ = [
     "analyze_hlo",
     "collective_bytes_from_hlo",
     "input_output_aliases_from_hlo",
+    "op_names_from_hlo",
 ]
 
 _COLLECTIVE_RE = re.compile(
@@ -71,6 +78,8 @@ _GROUP_RE = re.compile(r"\{([0-9,\s]*)\}")
 _CONVERT_RE = re.compile(r"=\s*([a-z0-9]+)\[[0-9,]*\][^=]*?"
                          r"\bconvert(?:\.\d+)?\s*\(\s*([a-z0-9]+)\[")
 _SCATTER_RE = re.compile(r"=\s*[^=]*?\bscatter(?:\.\d+)?\s*\(")
+_OP_NAME_RE = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?\bop_name="([^"]*)"', re.M)
 
 
 def _shape_entries(region: str) -> list:
@@ -251,3 +260,14 @@ def input_output_aliases_from_hlo(hlo_text: str) -> list:
         out_idx = tuple(int(t) for t in om.split(",") if t.strip())
         pairs.append((out_idx if len(out_idx) != 1 else out_idx[0], int(pm)))
     return pairs
+
+
+def op_names_from_hlo(hlo_text: str) -> dict:
+    """{instruction name: op_name} over every computation of the module.
+
+    The op_name is JAX's name stack for the op the instruction came from
+    (a fusion carries its root's), e.g.
+    `jit(step)/transpose(jvp(edge.gather))/scatter-add`. Instructions with
+    no op_name (copies, bitcasts, constants, tuples) are left out.
+    """
+    return dict(_OP_NAME_RE.findall(hlo_text))

@@ -24,6 +24,7 @@ from typing import Callable
 import numpy as np
 
 from repro.core.graph import Graph
+from repro.obs.trace import get_tracer
 
 __all__ = ["EDGE_PARTITIONERS", "partition_edges"]
 
@@ -363,6 +364,8 @@ EDGE_PARTITIONERS: dict[str, Callable[..., np.ndarray]] = {
 def partition_edges(graph: Graph, k: int, method: str, seed: int = 0, **kw) -> np.ndarray:
     if method not in EDGE_PARTITIONERS:
         raise ValueError(f"unknown edge partitioner {method!r}; options: {sorted(EDGE_PARTITIONERS)}")
-    out = EDGE_PARTITIONERS[method](graph, k, seed=seed, **kw)
+    with get_tracer().span("partition.edges", cat="setup",
+                           args={"method": method, "k": k}):
+        out = EDGE_PARTITIONERS[method](graph, k, seed=seed, **kw)
     assert out.shape == (graph.num_edges,)
     return out.astype(np.int32)

@@ -51,6 +51,7 @@ from repro.kernels.tiling import (
     tiled_need_per_tile,
     tiled_shape,
 )
+from repro.obs.trace import get_tracer
 
 __all__ = [
     "BlockRowBook",
@@ -162,7 +163,15 @@ def build_edge_book(
     """`tiled_layout` additionally builds the per-partition tiled aggregation
     layout (agg_order/agg_ldst) — only the tiled/pallas backends read it, so
     the default scatter path skips the host sort and the device residency
-    (the fields are then empty [k, 0] arrays)."""
+    (the fields are then empty [k, 0] arrays). Timed as the `book.build`
+    span, tiled layout included."""
+    with get_tracer().span("book.build", cat="setup",
+                           args={"k": k, "tiled_layout": tiled_layout}):
+        return _edge_book(graph, edge_assignment, k, tiled_layout)
+
+
+def _edge_book(graph: Graph, edge_assignment: np.ndarray, k: int,
+               tiled_layout: bool) -> EdgePartitionBook:
     assignment = np.asarray(edge_assignment, dtype=np.int64)
     V = graph.num_vertices
     src = graph.src.astype(np.int64)

@@ -316,20 +316,32 @@ class FullBatchTrainer:
         )
 
     def train_step(self) -> float:
-        with get_tracer().span("fullbatch.step", cat="step",
-                               args={"sync": self.sync_mode}):
-            if as_codec(self.codec).lossless:
+        """One epoch. Inside the `fullbatch.step` span, two contiguous
+        phases: `fullbatch.step.dispatch` (the call into the jitted step,
+        until it returns) and `fullbatch.step.wait` (reading the loss back,
+        which waits for the device)."""
+        tracer = get_tracer()
+        with tracer.span("fullbatch.step", cat="step",
+                         args={"sync": self.sync_mode}):
+            lossless = as_codec(self.codec).lossless
+            if not lossless and self.ef_state is None:
+                self.ef_state = self._init_ef()
+            clock = tracer.phase_clock(cat="step",
+                                       first="fullbatch.step.dispatch")
+            if lossless:
                 loss, self.params, self.opt_state = self._train_step(
                     self.params, self.opt_state, self.blocks
                 )
-                return float(loss)
-            if self.ef_state is None:
-                self.ef_state = self._init_ef()
-            loss, self.params, self.opt_state, self.ef_state = \
-                self._train_step(
-                    self.params, self.opt_state, self.blocks, self.ef_state
-                )
-            return float(loss)
+            else:
+                loss, self.params, self.opt_state, self.ef_state = \
+                    self._train_step(
+                        self.params, self.opt_state, self.blocks,
+                        self.ef_state
+                    )
+            clock.split("fullbatch.step.dispatch", then="fullbatch.step.wait")
+            loss = float(loss)
+            clock.split("fullbatch.step.wait")
+            return loss
 
     def set_epoch(self, epoch: int) -> None:
         """Advance epoch-scheduled codecs (VariableRatioCodec). Re-jits the

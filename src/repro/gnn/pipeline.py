@@ -254,7 +254,8 @@ class BatchPreparer:
         if self.injector is not None:
             self.injector.at_step(index)
         clock = get_tracer().phase_clock(cat="pipeline",
-                                         args={"step": index})
+                                         args={"step": index},
+                                         first="pipeline.sample")
         seqs = self._step_seed_seqs()
         counts = self._seed_counts(seed_share)
         jobs = [(index, w, ss, int(counts[w])) for w, ss in enumerate(seqs)]
@@ -263,9 +264,9 @@ class BatchPreparer:
                 lambda job: self._sample_job(*job), jobs))
         else:
             batches = [self._sample_job(*job) for job in jobs]
-        sample_time = clock.split("pipeline.sample")
+        sample_time = clock.split("pipeline.sample", then="pipeline.fetch")
         stacked_np, fetch = self._stack_batches(index, batches)
-        fetch_time = clock.split("pipeline.fetch")
+        fetch_time = clock.split("pipeline.fetch", then="pipeline.transfer")
         stacked = jax.device_put(stacked_np)
         stacked = jax.block_until_ready(stacked)
         transfer_time = clock.split("pipeline.transfer")

@@ -206,11 +206,12 @@ class _PartialAggSync(_CodecSync):
                        reduce: str = "sum", backend: str = "scatter"):
         object.__setattr__(self, "_cur_layer", self._take_layer())
         n = payload.shape[0]
-        messages = jnp.concatenate([
-            msg_fn(payload[blk.esrc], blk.edst, blk.emask),
-            msg_fn(payload[blk.edst], blk.esrc, blk.emask),
-        ], axis=0)
-        dst = jnp.concatenate([blk.edst, blk.esrc], axis=0)
+        with jax.named_scope("edge.gather"):
+            messages = jnp.concatenate([
+                msg_fn(payload[blk.esrc], blk.edst, blk.emask),
+                msg_fn(payload[blk.edst], blk.esrc, blk.emask),
+            ], axis=0)
+            dst = jnp.concatenate([blk.edst, blk.esrc], axis=0)
         agg = ops.aggregate(
             messages, dst, n,
             edge_order=blk.agg_order, local_dst=blk.agg_ldst,

@@ -135,13 +135,16 @@ def _tiled_aggregate(num_rows, tile_v, block_e, use_pallas, interpret,
     """
     del dst  # forward uses the tiled layout only; dst feeds the backward
     e, f = messages.shape
-    msg_pad = jnp.concatenate(
-        [messages, jnp.zeros((1, f), messages.dtype)], axis=0)
-    return segment_spmm(
-        msg_pad[edge_order], local_dst, num_rows,
-        tile_v=tile_v, block_e=block_e,
-        use_pallas=use_pallas, interpret=interpret,
-    )
+    with jax.named_scope("edge.reorder"):
+        msg_pad = jnp.concatenate(
+            [messages, jnp.zeros((1, f), messages.dtype)], axis=0)
+        ordered = msg_pad[edge_order]
+    with jax.named_scope("edge.reduce"):
+        return segment_spmm(
+            ordered, local_dst, num_rows,
+            tile_v=tile_v, block_e=block_e,
+            use_pallas=use_pallas, interpret=interpret,
+        )
 
 
 def _tiled_aggregate_fwd(num_rows, tile_v, block_e, use_pallas, interpret,
@@ -151,6 +154,7 @@ def _tiled_aggregate_fwd(num_rows, tile_v, block_e, use_pallas, interpret,
     return out, dst
 
 
+@jax.named_scope("edge.reduce")
 def _tiled_aggregate_bwd(num_rows, tile_v, block_e, use_pallas, interpret,
                          dst, g):
     # transpose of the pre-sorted scatter-add: a gather (pad dst -> zero row)
@@ -184,13 +188,16 @@ def _tiled_aggregate_max(num_rows, tile_v, block_e, use_pallas, interpret,
     """
     del dst  # forward uses the tiled layout only; dst feeds the backward
     e, f = messages.shape
-    msg_pad = jnp.concatenate(
-        [messages, jnp.full((1, f), -jnp.inf, messages.dtype)], axis=0)
-    return segment_spmm(
-        msg_pad[edge_order], local_dst, num_rows,
-        combiner="max", tile_v=tile_v, block_e=block_e,
-        use_pallas=use_pallas, interpret=interpret,
-    )
+    with jax.named_scope("edge.reorder"):
+        msg_pad = jnp.concatenate(
+            [messages, jnp.full((1, f), -jnp.inf, messages.dtype)], axis=0)
+        ordered = msg_pad[edge_order]
+    with jax.named_scope("edge.reduce"):
+        return segment_spmm(
+            ordered, local_dst, num_rows,
+            combiner="max", tile_v=tile_v, block_e=block_e,
+            use_pallas=use_pallas, interpret=interpret,
+        )
 
 
 def _tiled_aggregate_max_fwd(num_rows, tile_v, block_e, use_pallas, interpret,
@@ -200,6 +207,7 @@ def _tiled_aggregate_max_fwd(num_rows, tile_v, block_e, use_pallas, interpret,
     return out, (messages, dst, out, edge_order, local_dst)
 
 
+@jax.named_scope("edge.reduce")
 def _tiled_aggregate_max_bwd(num_rows, tile_v, block_e, use_pallas, interpret,
                              res, g):
     messages, dst, out, edge_order, local_dst = res
@@ -285,12 +293,15 @@ def aggregate(
         raise ValueError(f"unknown aggregate reduce {reduce!r}; "
                          f"options: {AGG_REDUCES}")
     if backend == "scatter":
-        if reduce == "max":
-            out = jnp.full((num_rows + 1, messages.shape[-1]), -jnp.inf,
-                           messages.dtype)
-            return out.at[jnp.minimum(dst, num_rows)].max(messages)[:num_rows]
-        out = jnp.zeros((num_rows + 1, messages.shape[-1]), messages.dtype)
-        return out.at[jnp.minimum(dst, num_rows)].add(messages)[:num_rows]
+        with jax.named_scope("edge.reduce"):
+            if reduce == "max":
+                out = jnp.full((num_rows + 1, messages.shape[-1]), -jnp.inf,
+                               messages.dtype)
+                return out.at[jnp.minimum(dst, num_rows)].max(
+                    messages)[:num_rows]
+            out = jnp.zeros((num_rows + 1, messages.shape[-1]),
+                            messages.dtype)
+            return out.at[jnp.minimum(dst, num_rows)].add(messages)[:num_rows]
     if backend not in AGG_BACKENDS:
         raise ValueError(f"unknown aggregate backend {backend!r}; "
                          f"options: {AGG_BACKENDS}")

@@ -184,4 +184,5 @@ def segment_spmm(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=f"segment_spmm_{combiner}",
     )(dst_blocks, messages)

@@ -1,9 +1,11 @@
 """Unified tracing & telemetry: span timelines, counter tracks, and
 measured-vs-model reconciliation.
 
-  trace     — thread-aware span tracer (context-manager + decorator API,
-              monotonic clocks, ring-buffered events; a disabled no-op
-              singleton keeps the hot paths untouched by default)
+  trace     — thread-aware span tracer (context-manager API, monotonic
+              clocks, ring-buffered events; a disabled no-op singleton
+              keeps the hot paths untouched by default; an enabled one
+              puts its live spans on the JAX profiler's timeline as
+              `repro/<name>` and counts JAX's compiles)
   export    — Chrome trace-event / Perfetto JSON (schema gnn-trace/v1)
               with one track per worker/thread plus counter tracks
   aggregate — shared span/metric reductions (phase means, span stats,
@@ -16,7 +18,7 @@ from .aggregate import PHASES, phase_means, request_breakdown, span_summary
 from .export import (TRACE_SCHEMA, load_trace, to_chrome_trace,
                      validate_chrome_trace, write_trace)
 from .trace import (CollectiveEvent, CounterEvent, PhaseClock, Span,
-                    SpanEvent, Tracer, get_tracer, install, traced, tracing,
+                    SpanEvent, Tracer, get_tracer, install, tracing,
                     uninstall)
 
 __all__ = [
@@ -24,5 +26,5 @@ __all__ = [
     "TRACE_SCHEMA", "load_trace", "to_chrome_trace", "validate_chrome_trace",
     "write_trace",
     "CollectiveEvent", "CounterEvent", "PhaseClock", "Span", "SpanEvent",
-    "Tracer", "get_tracer", "install", "traced", "tracing", "uninstall",
+    "Tracer", "get_tracer", "install", "tracing", "uninstall",
 ]

@@ -10,9 +10,10 @@ Design constraints, in order:
 
   * **Disabled means untouched.** The module-level singleton starts
     disabled; every record method is a single attribute check away from a
-    no-op, and the :class:`PhaseClock` used on the pipeline hot path
-    takes exactly as many ``perf_counter()`` readings as the inline
-    timestamps it replaced. The "four phases sum exactly to the step
+    no-op, its spans and phase clocks are plain timers that record and
+    annotate nothing, and the :class:`PhaseClock` used on the pipeline
+    hot path takes exactly as many ``perf_counter()`` readings as the
+    inline timestamps it replaced. The "four phases sum exactly to the step
     wall" invariant and the overlapped==serial bitwise tests hold with
     tracing on or off because the *timestamps themselves* are what feed
     ``StepMetrics`` — the spans are the same numbers, not a second clock.
@@ -33,24 +34,46 @@ Byte accounting rides the same tracer: cumulative counters (``add``),
 gauges (``gauge``) and trace-time collective records (``collective``, fed
 by the sync strategies while jax traces the step function) are what
 ``obs.reconcile`` holds against the analytic cost model.
+
+On the profiler's clock. While an enabled tracer is installed, every live
+span (``Tracer.span``, each phase of a ``PhaseClock``) also opens a
+``jax.profiler.TraceAnnotation`` named ``repro/<span name>``, so a JAX
+profile shows the program's own phases beside the device's ops; spans
+recorded from explicit timestamps (``record_span``) stay in the ring
+buffer only. The installed tracer also counts JAX's tracing, lowering and
+backend compiles (``jit.compile_s``, ``jit.compiles``; persistent-cache
+loads, which a backend compile includes, in ``jit.cache_load_s``) and
+records each garbage collection as a ``host.gc`` span.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
+import gc
 import threading
 import time
 from contextlib import contextmanager
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
+
+import jax
 
 __all__ = [
     "SpanEvent", "CounterEvent", "CollectiveEvent", "Span", "PhaseClock",
-    "Tracer", "get_tracer", "install", "uninstall", "tracing", "traced",
+    "Tracer", "get_tracer", "install", "uninstall", "tracing",
 ]
 
 _DEFAULT_CAPACITY = 1 << 16
+
+#: prefix of the program's spans on the JAX profiler's timeline
+ANNOTATION_PREFIX = "repro/"
+
+# JAX's compile events, each reported with its start and end as it closes
+_JIT_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration",
+               "/jax/core/compile/backend_compile_duration")
+_BACKEND_COMPILE = _JIT_EVENTS[2]
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,9 +125,18 @@ class CollectiveEvent:
     program: str = "sync"
 
 
+def _annotation(name: str):
+    """Open a profiler annotation for the span ``name``; the caller closes
+    it with ``__exit__``."""
+    ann = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """Context-manager span. Always measures (``duration`` is consumed by
-    the call sites even when tracing is off); records only when enabled."""
+    the call sites even when tracing is off); a disabled tracer hands out
+    this plain form, which records nothing."""
 
     __slots__ = ("_tracer", "name", "cat", "track", "args", "t0", "t1")
 
@@ -124,14 +156,27 @@ class Span:
 
     def __exit__(self, *exc) -> None:
         self.t1 = time.perf_counter()
-        tr = self._tracer
-        if tr.enabled:
-            tr.record_span(self.name, self.t0, self.t1, cat=self.cat,
-                           track=self.track, args=self.args)
 
     @property
     def duration(self) -> float:
         return self.t1 - self.t0
+
+
+class _LiveSpan(Span):
+    """The enabled tracer's span: also a profiler annotation, and recorded
+    when it closes."""
+
+    __slots__ = ("_ann",)
+
+    def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name)
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__()
+        self._ann.__exit__(None, None, None)
+        self._tracer.record_span(self.name, self.t0, self.t1, cat=self.cat,
+                                 track=self.track, args=self.args)
 
 
 class PhaseClock:
@@ -139,7 +184,12 @@ class PhaseClock:
     the exact instant the next one opens, so phase durations sum to the
     wall *bitwise* (the same ``perf_counter`` reading ends one span and
     starts the next — no gap, no overlap, and exactly one clock reading
-    per boundary, matching the inline ``t0..t3`` code it replaced)."""
+    per boundary, matching the inline ``t0..t3`` code it replaced).
+
+    A phase is named when it closes; the profiler's annotation needs its
+    name when it opens, so ``first`` (at ``Tracer.phase_clock``) and each
+    split's ``then`` name the phase that opens. A disabled tracer hands
+    out this plain form, which records nothing."""
 
     __slots__ = ("_tracer", "cat", "track", "args", "_t")
 
@@ -151,15 +201,34 @@ class PhaseClock:
         self.args = args
         self._t = time.perf_counter()
 
-    def split(self, name: str) -> float:
-        """Close the running phase as ``name``; return its duration."""
+    def split(self, name: str, then: Optional[str] = None) -> float:
+        """Close the running phase as ``name``; return its duration.
+        ``then`` names the phase that opens, for the profiler's timeline."""
         t0, t1 = self._t, time.perf_counter()
         self._t = t1
-        tr = self._tracer
-        if tr.enabled:
-            tr.record_span(name, t0, t1, cat=self.cat, track=self.track,
-                           args=self.args)
         return t1 - t0
+
+
+class _LivePhaseClock(PhaseClock):
+    """The enabled tracer's phase clock: records each phase, and keeps one
+    profiler annotation open for the running phase."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, tracer: "Tracer", cat: str, track: Optional[str],
+                 args: Optional[dict], first: Optional[str]):
+        self._ann = _annotation(first) if first else None
+        super().__init__(tracer, cat, track, args)
+
+    def split(self, name: str, then: Optional[str] = None) -> float:
+        t0 = self._t
+        duration = super().split(name)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._ann = _annotation(then) if then else None
+        self._tracer.record_span(name, t0, self._t, cat=self.cat,
+                                 track=self.track, args=self.args)
+        return duration
 
 
 class Tracer:
@@ -176,17 +245,26 @@ class Tracer:
             maxlen=capacity)
         self._totals: Dict[str, float] = {}
         self._lock = threading.Lock()
+        self._jit_spans: List[List[float]] = []   # merged compile intervals
+        self._gc: Optional[tuple] = None          # (annotation, t0) of a gc
 
     # -- recording ----------------------------------------------------------
 
     def span(self, name: str, *, cat: str = "span",
              track: Optional[str] = None,
              args: Optional[dict] = None) -> Span:
+        if self.enabled:
+            return _LiveSpan(self, name, cat, track, args)
         return Span(self, name, cat, track, args)
 
     def phase_clock(self, *, cat: str = "phase",
                     track: Optional[str] = None,
-                    args: Optional[dict] = None) -> PhaseClock:
+                    args: Optional[dict] = None,
+                    first: Optional[str] = None) -> PhaseClock:
+        """A clock whose first phase opens now; ``first`` names it for the
+        profiler's timeline."""
+        if self.enabled:
+            return _LivePhaseClock(self, cat, track, args, first)
         return PhaseClock(self, cat, track, args)
 
     def record_span(self, name: str, t0: float, t1: float, *,
@@ -236,6 +314,36 @@ class Tracer:
             wire_bytes=None if wire_bytes is None else int(wire_bytes),
             layer=layer, program=program))
 
+    def _jit(self, event: str, start: float, end: float) -> None:
+        """One JAX compile event. A jit traced inside another reports its
+        own event inside the outer one's, so only the seconds the union of
+        the events grows by count."""
+        with self._lock:
+            grown, lo, hi, keep = end - start, start, end, []
+            for a, b in self._jit_spans:
+                if b < start or a > end:
+                    keep.append([a, b])
+                else:
+                    grown -= min(b, end) - max(a, start)
+                    lo, hi = min(lo, a), max(hi, b)
+            keep.append([lo, hi])
+            self._jit_spans = keep
+        self.add("jit.compile_s", grown)
+        if event == _BACKEND_COMPILE:
+            self.add("jit.compiles", 1)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """`gc.callbacks` hook: each collection as a ``host.gc`` span."""
+        if phase == "start":
+            self._gc = (_annotation("host.gc"), time.perf_counter())
+        elif self._gc is not None:
+            ann, t0 = self._gc
+            self._gc = None
+            t1 = time.perf_counter()
+            ann.__exit__(None, None, None)
+            self.record_span("host.gc", t0, t1, cat="gc",
+                             args={"generation": info["generation"]})
+
     # -- reading ------------------------------------------------------------
 
     def spans(self, name: Optional[str] = None) -> List[SpanEvent]:
@@ -273,6 +381,7 @@ class Tracer:
         self._collectives.clear()
         with self._lock:
             self._totals.clear()
+            self._jit_spans.clear()
 
 
 # -- module-level singleton -------------------------------------------------
@@ -287,16 +396,20 @@ def get_tracer() -> Tracer:
 
 
 def install(tracer: Tracer) -> Tracer:
-    """Install ``tracer`` as the process-wide sink; returns it."""
+    """Install ``tracer`` as the process-wide sink; returns it. An enabled
+    tracer also hooks the garbage collector while it is installed."""
     global _current
+    if _current._on_gc in gc.callbacks:
+        gc.callbacks.remove(_current._on_gc)
     _current = tracer
+    if tracer.enabled:
+        gc.callbacks.append(tracer._on_gc)
     return tracer
 
 
 def uninstall() -> None:
     """Restore the disabled no-op singleton."""
-    global _current
-    _current = _NULL
+    install(_NULL)
 
 
 @contextmanager
@@ -310,17 +423,17 @@ def tracing(capacity: int = _DEFAULT_CAPACITY) -> Iterator[Tracer]:
         install(prev)
 
 
-def traced(name: Optional[str] = None, *, cat: str = "fn",
-           track: Optional[str] = None) -> Callable:
-    """Decorator API: run the wrapped call under a span. Resolves the
-    tracer at call time, so functions decorated at import time respect a
-    later ``install()``."""
-    def deco(fn: Callable) -> Callable:
-        label = name or fn.__qualname__
+def _on_jit_event(event: str, start: float, end: float, **_kw) -> None:
+    tr = _current
+    if tr.enabled and event in _JIT_EVENTS:
+        tr._jit(event, start, end)
 
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with get_tracer().span(label, cat=cat, track=track):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
+
+def _on_duration_event(event: str, seconds: float, **_kw) -> None:
+    tr = _current
+    if tr.enabled and event == _CACHE_LOAD:
+        tr.add("jit.cache_load_s", seconds)
+
+
+jax.monitoring.register_event_time_span_listener(_on_jit_event)
+jax.monitoring.register_event_duration_secs_listener(_on_duration_event)

@@ -28,6 +28,7 @@ from repro.analysis import (
     input_output_aliases_from_hlo,
     iter_eqns,
     narrowing_converts,
+    op_names_from_hlo,
     primitive_names,
     run_rules,
     violation_program,
@@ -132,6 +133,62 @@ def test_hlo_input_output_alias_header():
            " {1}: (3, {}, may-alias) }, entry_computation_layout=...")
     assert input_output_aliases_from_hlo(hlo) == [(0, 1), (1, 3)]
     assert input_output_aliases_from_hlo("HloModule jit_f\n  x = f32[]") == []
+
+
+def test_hlo_op_names_from_metadata():
+    """Each instruction maps to its metadata's op_name, in every
+    computation; instructions without one are left out."""
+    hlo = """
+      %fused_computation.2 (p: f32[8,4]) -> f32[8,4] {
+        %gather.1 = f32[8,4]{1,0} gather(%p, %i), metadata={op_name="jit(step)/jvp(edge.reorder)/gather" source_file="ops.py" source_line=3}
+      }
+      ENTRY %main {
+        %fusion.2 = f32[8,4]{1,0:T(8,128)S(1)} fusion(%a), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(step)/jvp(edge.reorder)/gather" stack_frame_id=4}
+        %segment_spmm_sum.3 = f32[8,4]{1,0} custom-call(%b, %fusion.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(edge.reduce)/jit(segment_spmm)/segment_spmm_sum/pallas_call"}
+        %copy-start.1 = (f32[8,4]{1,0}, u32[]) copy-start(%fusion.2)
+        ROOT %fusion.14 = f32[9,4]{1,0} fusion(%g), kind=kOutput, metadata={op_name="jit(step)/transpose(jvp(edge.gather))/scatter-add"}
+      }
+    """
+    assert op_names_from_hlo(hlo) == {
+        "gather.1": "jit(step)/jvp(edge.reorder)/gather",
+        "fusion.2": "jit(step)/jvp(edge.reorder)/gather",
+        "segment_spmm_sum.3":
+            "jit(step)/jvp(edge.reduce)/jit(segment_spmm)/segment_spmm_sum/"
+            "pallas_call",
+        "fusion.14": "jit(step)/transpose(jvp(edge.gather))/scatter-add",
+    }
+
+
+def test_compiled_step_names_the_edge_path():
+    """A compiled full-batch step carries the edge path's scopes: the
+    message gathers, the reorder into the tiled layout and the reduce
+    forward; the gathers' and the reduce's backward under `transpose(`.
+    The reorder has none of its own: the aggregate's custom backward
+    gathers the row gradients in the original edge order."""
+    from repro.core.edge_partition import partition_edges
+    from repro.core.graph import generate_graph
+    from repro.gnn.fullbatch import FullBatchTrainer
+    from repro.gnn.models import GNNSpec
+
+    g = generate_graph("social", 150, 900, seed=3)
+    rng = np.random.default_rng(0)
+    feats = rng.normal(size=(g.num_vertices, 12)).astype(np.float32)
+    labels = rng.integers(0, 5, g.num_vertices).astype(np.int32)
+    train = rng.random(g.num_vertices) < 0.4
+    spec = GNNSpec(model="sage", feature_dim=12, hidden_dim=8, num_classes=5,
+                   num_layers=2, agg_backend="tiled")
+    tr = FullBatchTrainer.build(g, partition_edges(g, 1, "random", seed=0),
+                                1, spec, feats, labels, train)
+    text = tr._train_step.lower(tr.params, tr.opt_state,
+                                tr.blocks).compile().as_text()
+    names = set(op_names_from_hlo(text).values())
+    scopes = ("edge.gather", "edge.reorder", "edge.reduce")
+    forward = {sc for sc in scopes for n in names
+               if f"jvp({sc})" in n and "transpose(" not in n}
+    backward = {sc for sc in scopes for n in names
+                if f"transpose(jvp({sc}))" in n}
+    assert forward == set(scopes)
+    assert backward == {"edge.gather", "edge.reduce"}
 
 
 def test_collective_bytes_historical_shape():

@@ -427,3 +427,170 @@ def test_install_uninstall_restores_null():
     uninstall()
     assert get_tracer() is prev
     assert not get_tracer().enabled
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock: live spans as `repro/` annotations, JAX's compiles,
+# garbage collections
+# ---------------------------------------------------------------------------
+
+
+def _fullbatch_trainer(node_setup):
+    g, feats, labels, train = node_setup
+    spec = GNNSpec(model="sage", feature_dim=12, hidden_dim=8, num_classes=5,
+                   num_layers=2, agg_backend="tiled")
+    a = partition_edges(g, 1, "random", seed=0)
+    trainer = FullBatchTrainer.build(g, a, 1, spec, feats, labels, train,
+                                     sync_mode="halo", mode="sim")
+    trainer.train_step()  # compiles outside the measured part
+    return trainer
+
+
+def _profile_host_events(log_dir, fn) -> list:
+    """(line, name, start_ns, end_ns) of every host event of a JAX profile
+    of `fn()`, which runs inside a `caller/step` annotation."""
+    import glob
+    import os
+
+    import jax
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        with jax.profiler.TraceAnnotation("caller/step"):
+            fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    prof = jax.profiler.ProfileData.from_file(path)
+    return [(line.name, e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in prof.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+def test_step_phases_land_on_the_profile(tmp_path, node_setup):
+    """With an enabled tracer the full-batch step's span and its two
+    contiguous phases are annotations of the profile, nested in the
+    caller's; with the tracer disabled the profile holds no `repro/`
+    event."""
+    trainer = _fullbatch_trainer(node_setup)
+    with tracing() as tr:
+        host = _profile_host_events(tmp_path / "on", trainer.train_step)
+    by_name = {n: (ln, s, e) for ln, n, s, e in host}
+    line, s0, e0 = by_name["caller/step"]
+    names = ("repro/fullbatch.step", "repro/fullbatch.step.dispatch",
+             "repro/fullbatch.step.wait")
+    for name in names:
+        ln, s, e = by_name[name]
+        assert ln == line and s0 <= s <= e <= e0, name
+    dispatch, wait = by_name[names[1]], by_name[names[2]]
+    step = by_name[names[0]]
+    assert step[1] <= dispatch[1] <= dispatch[2] <= wait[1] <= wait[2] <= step[2]
+    recorded = {s.name for s in tr.spans()}
+    assert {n[len("repro/"):] for n in names} <= recorded
+    host = _profile_host_events(tmp_path / "off", trainer.train_step)
+    assert not [n for _, n, _, _ in host if n.startswith("repro/")]
+
+
+def _count_annotations(monkeypatch) -> list:
+    """Make every `jax.profiler.TraceAnnotation` record its name when it
+    is built, and whether it was closed."""
+    import jax
+
+    made = []
+
+    class Counted(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            super().__init__(name, **kw)
+            made.append([name, False])
+            self._i = len(made) - 1
+
+        def __exit__(self, *exc):
+            made[self._i][1] = True
+            return super().__exit__(*exc)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counted)
+    return made
+
+
+def test_disabled_tracer_builds_no_annotation(monkeypatch, node_setup):
+    trainer = _fullbatch_trainer(node_setup)
+    made = _count_annotations(monkeypatch)
+    trainer.train_step()
+    with get_tracer().span("x"):
+        pass
+    get_tracer().phase_clock(first="p").split("p")
+    assert made == []
+    with tracing():
+        trainer.train_step()
+    names = [n for n, _ in made]
+    assert {"repro/fullbatch.step", "repro/fullbatch.step.dispatch",
+            "repro/fullbatch.step.wait"} <= set(names)
+    assert all(closed for _, closed in made)
+
+
+def test_phase_clock_keeps_one_annotation_open(monkeypatch):
+    """Each phase is one annotation, named by `first` or the split's
+    `then`, closed at the split that closes the phase."""
+    made = _count_annotations(monkeypatch)
+    with tracing() as tr:
+        clock = tr.phase_clock(cat="test", first="p0")
+        assert made == [["repro/p0", False]]
+        clock.split("p0", then="p1")
+        assert made == [["repro/p0", True], ["repro/p1", False]]
+        clock.split("p1")
+    assert made == [["repro/p0", True], ["repro/p1", True]]
+    assert [s.name for s in tr.spans()] == ["p0", "p1"]
+
+
+def test_jit_counter_counts_nested_compiles_once():
+    """JAX reports a jit traced inside another inside the outer one's
+    interval: the total is the union's length. Backend compiles are
+    counted, cache loads summed apart; nothing is counted while no enabled
+    tracer is installed."""
+    import jax
+
+    trace_ev = "/jax/core/compile/jaxpr_trace_duration"
+    backend_ev = "/jax/core/compile/backend_compile_duration"
+    span = jax.monitoring.record_event_time_span
+    with tracing() as tr:
+        span(trace_ev, 10.0, 10.5)    # an inner jit's trace ...
+        span(trace_ev, 9.0, 12.0)     # ... inside the outer one's
+        span(backend_ev, 12.0, 13.0)
+        span(backend_ev, 20.0, 21.0)
+        jax.monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+    assert tr.total("jit.compile_s") == pytest.approx(5.0)
+    assert tr.total("jit.compiles") == 2
+    assert tr.total("jit.cache_load_s") == 0.25
+    span(backend_ev, 30.0, 31.0)
+    assert tr.total("jit.compiles") == 2
+
+
+def test_jit_counter_sees_a_real_compile():
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones(3)
+    inner = jax.jit(lambda v: v * 3.0)
+    outer = jax.jit(lambda v: inner(v) + inner(v + 1.0))
+    with tracing() as tr:
+        t0 = time.perf_counter()
+        outer(x).block_until_ready()
+        wall = time.perf_counter() - t0
+    assert tr.total("jit.compiles") == 1
+    assert 0.0 < tr.total("jit.compile_s") <= wall
+
+
+def test_gc_collections_are_spans_while_installed():
+    import gc
+
+    with tracing() as tr:
+        gc.collect()
+    spans = tr.spans("host.gc")
+    assert spans and spans[-1].args == {"generation": 2}
+    assert all(s.t1 >= s.t0 for s in spans)
+    gc.collect()
+    assert len(tr.spans("host.gc")) == len(spans)

@@ -13,6 +13,7 @@ Also here: the persistent compile cache the entry points switch on.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis.hlo import op_names_from_hlo
 from repro.kernels import ops
 from repro.launch import compile_cache
 
@@ -89,7 +91,9 @@ def test_segment_spmm_compiles_for_v5e(one_chip, kernel_dispatch, combiner,
 def test_fullbatch_step_compiles_for_v5e(one_chip, kernel_dispatch, model,
                                          aggregates):
     """One full-batch train step at the paper width F = H = 512 on the
-    tiled backend: every layer's aggregates run the kernel."""
+    tiled backend: every layer's aggregates run the kernel, whose calls
+    the compiled step names by combiner, and the edge path's scopes
+    survive the TPU compiler's fusions."""
     from repro.core.graph import paper_graph
     from repro.gnn.fullbatch import FullBatchTrainer
     from repro.gnn.models import GNNSpec
@@ -107,6 +111,16 @@ def test_fullbatch_step_compiles_for_v5e(one_chip, kernel_dispatch, model,
         *_shapes((tr.params, tr.opt_state, tr.blocks), one_chip)).compile()
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") == aggregates * spec.num_layers
+    calls = re.findall(r"%(segment_spmm_(?:sum|max))\.\d+ = \S+ custom-call",
+                       hlo)
+    maxes = spec.num_layers if model == "gat" else 0
+    assert sorted(calls) == (["segment_spmm_max"] * maxes + [
+        "segment_spmm_sum"] * (aggregates * spec.num_layers - maxes))
+    names = set(op_names_from_hlo(hlo).values())
+    for scope in ("edge.gather", "edge.reorder", "edge.reduce"):
+        assert any(f"/jvp({scope})/" in n for n in names), scope
+    for scope in ("edge.gather", "edge.reduce"):
+        assert any(f"/transpose(jvp({scope}))/" in n for n in names), scope
 
 
 def test_compile_cache_follows_env(monkeypatch, tmp_path):
