@@ -75,14 +75,16 @@ def segment_reduce_bench() -> None:
     e, f = 16 * v, 64
     dst = rng.integers(0, v, e).astype(np.int32)
     msgs = jnp.asarray(rng.normal(size=(e, f)).astype(np.float32))
-    order, ldst, _ = ops.prepare_tiled_edges(dst, v)
+    order, ldst, btile, _ = ops.prepare_tiled_edges(dst, v)
     jdst = jnp.asarray(dst)
-    order, ldst = jnp.asarray(order), jnp.asarray(ldst)
+    order, ldst, btile = (jnp.asarray(order), jnp.asarray(ldst),
+                          jnp.asarray(btile))
     for reduce in ("sum", "max"):
         times = {}
         for backend in ("scatter", "tiled"):
             kw = ({} if backend == "scatter"
-                  else {"edge_order": order, "local_dst": ldst})
+                  else {"edge_order": order, "local_dst": ldst,
+                        "block_tile": btile})
             fn = jax.jit(lambda m, bk=backend, rd=reduce, kw=kw: ops.aggregate(
                 m, jdst, v, backend=bk, reduce=rd, **kw))
             times[backend] = _time_steps(

@@ -177,19 +177,20 @@ def phase_kernel() -> None:
     rng = np.random.default_rng(SEED)
     v, e = 4096, 131072
     dst = rng.integers(0, v, e).astype(np.int32)
-    order, ldst, rows = ops.prepare_tiled_edges(dst, v)
-    n_tiles = rows // ops.DEFAULT_TILE_V
-    blocks = order.shape[0] // n_tiles // ops.DEFAULT_BLOCK_E
-    say(f"kernel: F={FEATURES} rows={rows} edge blocks per row tile="
+    order, ldst, btile, rows = ops.prepare_tiled_edges(dst, v)
+    blocks = int(np.bincount(btile).min())
+    say(f"kernel: F={FEATURES} rows={rows} edge blocks per row tile>="
         f"{blocks}")
     check(blocks > 1, "one edge block per row tile")
     msgs = rng.normal(size=(e, FEATURES)).astype(np.float32)
-    ldst = jnp.asarray(ldst)
+    ldst, btile = jnp.asarray(ldst), jnp.asarray(btile)
     for combiner, fill in (("sum", 0.0), ("max", -np.inf)):
         pad = jnp.asarray(np.concatenate(
             [msgs, np.full((1, FEATURES), fill, np.float32)])[order])
-        got = np.asarray(ops.segment_spmm(pad, ldst, rows, combiner=combiner))
-        want = np.asarray(ops.segment_spmm(pad, ldst, rows, combiner=combiner,
+        got = np.asarray(ops.segment_spmm(pad, ldst, rows, block_tile=btile,
+                                          combiner=combiner))
+        want = np.asarray(ops.segment_spmm(pad, ldst, rows, block_tile=btile,
+                                           combiner=combiner,
                                            use_pallas=False))
         if combiner == "max":
             check(np.array_equal(np.isinf(got), np.isinf(want)),
@@ -200,7 +201,7 @@ def phase_kernel() -> None:
             check(diff == 0.0, "kernel max differs from the oracle")
             continue
         bound = SUM_ROUNDING * np.asarray(ops.segment_spmm(
-            jnp.abs(pad), ldst, rows, use_pallas=False))
+            jnp.abs(pad), ldst, rows, block_tile=btile, use_pallas=False))
         diff = np.abs(got - want)
         ratio = float((diff / np.maximum(bound, 1e-30)).max())
         say(f"kernel: sum max|kernel - oracle|={float(diff.max())!r} "
@@ -210,16 +211,14 @@ def phase_kernel() -> None:
 
 def phase_fullbatch(model: str, scale: float) -> None:
     from repro.core.graph import paper_graph
-    from repro.kernels.tiling import DEFAULT_BLOCK_E, tiled_shape
 
     g = paper_graph("OR", scale=scale, seed=SEED)
     say(f"{model}: OR scale={scale} V={g.num_vertices} E={g.num_edges}")
     data = node_data(g)
     tr, logits, losses, hlo = train_fullbatch(
         g, data, spec_for(model, "tiled"), 1)
-    _, n_tiles = tiled_shape(tr.book.v_max + 1)
-    blocks = tr.book.agg_order.shape[1] // n_tiles // DEFAULT_BLOCK_E
-    say(f"{model}: edge blocks per row tile={blocks}")
+    blocks = int(np.bincount(tr.book.agg_btile[0]).max())
+    say(f"{model}: edge blocks of the busiest row tile={blocks}")
     check(blocks > 1, f"{model}: one edge block per row tile")
     check_kernel_ran(model, hlo)
     check_losses(model, losses)

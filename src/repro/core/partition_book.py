@@ -47,9 +47,9 @@ import numpy as np
 
 from repro.core.graph import Graph
 from repro.kernels.tiling import (
+    DEFAULT_BLOCK_E,
     prepare_tiled_edges,
-    tiled_need_per_tile,
-    tiled_shape,
+    tiled_need_blocks,
 )
 from repro.obs.trace import get_tracer
 
@@ -97,15 +97,17 @@ class EdgePartitionBook:
 
     # tiled aggregation layout (kernels.tiling.prepare_tiled_edges, built
     # with the DEFAULT_TILE_V/DEFAULT_BLOCK_E tiling `ops.aggregate` expects)
-    # over the SYMMETRISED edge list — dst sequence [edst | esrc], one layout
-    # per partition, padded to a uniform per-tile edge count so the stacked
-    # [k, ...] arrays share one static shape. Masked (padding) edges are
-    # dropped: their messages are identically zero. Empty [k, 0] unless the
-    # book was built with tiled_layout=True.
+    # over the SYMMETRISED edge list — dst sequence [edst | esrc], one ragged
+    # layout per partition, padded with trailing blocks to one shared block
+    # count so the stacked [k, ...] arrays share one static shape. Masked
+    # (padding) edges are dropped: their messages are identically zero.
+    # Empty [k, 0] unless the book was built with tiled_layout=True.
     # [k, E_tiled] gather indices into the 2*e_max message list (pad -> 2*e_max)
     agg_order: np.ndarray
     # [k, E_tiled] row id within the edge's row tile (pad -> DEFAULT_TILE_V)
     agg_ldst: np.ndarray
+    # [k, E_tiled / DEFAULT_BLOCK_E] int32 row tile of each edge block
+    agg_btile: np.ndarray
 
     def padding_waste(self) -> float:
         """Fraction of all_to_all payload that is padding (0 = perfect)."""
@@ -153,6 +155,23 @@ class EdgePartitionBook:
         return out
 
 
+def _stacked_layouts(dst: np.ndarray, valid: np.ndarray, num_rows: int
+                     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """One ragged tiled layout per row of `dst` [n, E] (edges where `valid`),
+    each padded with trailing blocks to the largest block count, so the
+    stacked (edge_order, local_dst, block_tile) share one static shape."""
+    n_blocks = max(tiled_need_blocks(d, num_rows, valid=m)
+                   for d, m in zip(dst, valid))
+    rows = n_blocks * DEFAULT_BLOCK_E
+    order = np.empty((dst.shape[0], rows), dtype=np.int64)
+    ldst = np.empty((dst.shape[0], rows), dtype=np.int32)
+    btile = np.empty((dst.shape[0], n_blocks), dtype=np.int32)
+    for i, (d, m) in enumerate(zip(dst, valid)):
+        order[i], ldst[i], btile[i], _ = prepare_tiled_edges(
+            d, num_rows, n_blocks=n_blocks, valid=m)
+    return order, ldst, btile
+
+
 def build_edge_book(
     graph: Graph,
     edge_assignment: np.ndarray,
@@ -161,10 +180,11 @@ def build_edge_book(
     tiled_layout: bool = False,
 ) -> EdgePartitionBook:
     """`tiled_layout` additionally builds the per-partition tiled aggregation
-    layout (agg_order/agg_ldst) — only the tiled/pallas backends read it, so
-    the default scatter path skips the host sort and the device residency
-    (the fields are then empty [k, 0] arrays). Timed as the `book.build`
-    span, tiled layout included."""
+    layout (agg_order/agg_ldst/agg_btile) — only the tiled/pallas backends
+    read it, so the default scatter path skips the host sort and the device
+    residency (the fields are then empty [k, 0] arrays). Timed as the
+    `book.build` span, tiled layout included, inside which the gauge
+    `tiled.pad_ratio` records the layout's rows over the real messages."""
     with get_tracer().span("book.build", cat="setup",
                            args={"k": k, "tiled_layout": tiled_layout}):
         return _edge_book(graph, edge_assignment, k, tiled_layout)
@@ -273,20 +293,14 @@ def _edge_book(graph: Graph, edge_assignment: np.ndarray, k: int,
     if tiled_layout:
         dst2 = np.concatenate([edst, esrc], axis=1)
         valid2 = np.concatenate([emask, emask], axis=1)
-        _, n_tiles = tiled_shape(v_max + 1)
-        per_tile = max(
-            tiled_need_per_tile(dst2[p], v_max + 1, valid=valid2[p])
-            for p in range(k)
-        )
-        agg_order = np.empty((k, per_tile * n_tiles), dtype=np.int64)
-        agg_ldst = np.empty((k, per_tile * n_tiles), dtype=np.int32)
-        for p in range(k):
-            agg_order[p], agg_ldst[p], _ = prepare_tiled_edges(
-                dst2[p], v_max + 1, per_tile=per_tile, valid=valid2[p],
-            )
+        agg_order, agg_ldst, agg_btile = _stacked_layouts(
+            dst2, valid2, v_max + 1)
+        get_tracer().gauge("tiled.pad_ratio",
+                           agg_order.size / max(int(valid2.sum()), 1))
     else:
         agg_order = np.zeros((k, 0), dtype=np.int64)
         agg_ldst = np.zeros((k, 0), dtype=np.int32)
+        agg_btile = np.zeros((k, 0), dtype=np.int32)
 
     return EdgePartitionBook(
         k=k,
@@ -308,6 +322,7 @@ def _edge_book(graph: Graph, edge_assignment: np.ndarray, k: int,
         replicas_total=int(mirror_pairs.sum()),
         agg_order=agg_order.astype(np.int32),
         agg_ldst=agg_ldst,
+        agg_btile=agg_btile,
     )
 
 
@@ -369,11 +384,12 @@ class BlockRowBook:
     chunk_emask: np.ndarray  # [k, k, c_max] bool
 
     # per-chunk tiled aggregation layouts (kernels.tiling.prepare_tiled_edges
-    # over chunk_edst with valid=chunk_emask, one shared per_tile so all k*k
-    # chunks stack to one static shape). Empty [k, k, 0] unless the book was
-    # built with tiled_layout=True.
+    # over chunk_edst with valid=chunk_emask, one shared block count so all
+    # k*k chunks stack to one static shape). Empty [k, k, 0] unless the book
+    # was built with tiled_layout=True.
     chunk_agg_order: np.ndarray  # [k, k, E_tiled] int32 (pad -> c_max)
     chunk_agg_ldst: np.ndarray   # [k, k, E_tiled] int32 (pad -> tile_v)
+    chunk_agg_btile: np.ndarray  # [k, k, E_tiled / block_e] int32 row tiles
 
     # masters == vmask: every vertex lives exactly once, on its block row
     @property
@@ -412,8 +428,9 @@ def build_blockrow_book(
 ) -> BlockRowBook:
     """1.5D book: contiguous vertex blocks, symmetrised edges chunked by
     (dst block, ring stage). `tiled_layout` additionally builds one
-    `prepare_tiled_edges` layout per chunk (shared per_tile, so the stacked
-    [k, k, ...] arrays have one static shape) for the tiled/pallas backends."""
+    `prepare_tiled_edges` layout per chunk (one shared block count, so the
+    stacked [k, k, ...] arrays have one static shape) for the tiled/pallas
+    backends."""
     V = graph.num_vertices
     v_block = -(-max(V, 1) // k)  # ceil(V / k)
 
@@ -452,26 +469,16 @@ def build_blockrow_book(
     chunk_emask[cp, cs, within] = True
 
     if tiled_layout:
-        n_rows = v_block + 1
-        _, n_tiles = tiled_shape(n_rows)
-        per_tile = max(
-            tiled_need_per_tile(chunk_edst[p, s], n_rows,
-                                valid=chunk_emask[p, s])
-            for p in range(k) for s in range(k)
-        )
-        e_tiled = per_tile * n_tiles
-        chunk_agg_order = np.empty((k, k, e_tiled), dtype=np.int64)
-        chunk_agg_ldst = np.empty((k, k, e_tiled), dtype=np.int32)
-        for p in range(k):
-            for s in range(k):
-                chunk_agg_order[p, s], chunk_agg_ldst[p, s], _ = (
-                    prepare_tiled_edges(
-                        chunk_edst[p, s], n_rows, per_tile=per_tile,
-                        valid=chunk_emask[p, s],
-                    ))
+        order, ldst, btile = _stacked_layouts(
+            chunk_edst.reshape(k * k, c_max),
+            chunk_emask.reshape(k * k, c_max), v_block + 1)
+        chunk_agg_order = order.reshape(k, k, -1)
+        chunk_agg_ldst = ldst.reshape(k, k, -1)
+        chunk_agg_btile = btile.reshape(k, k, -1)
     else:
         chunk_agg_order = np.zeros((k, k, 0), dtype=np.int64)
         chunk_agg_ldst = np.zeros((k, k, 0), dtype=np.int32)
+        chunk_agg_btile = np.zeros((k, k, 0), dtype=np.int32)
 
     return BlockRowBook(
         k=k,
@@ -486,6 +493,7 @@ def build_blockrow_book(
         chunk_emask=chunk_emask,
         chunk_agg_order=chunk_agg_order.astype(np.int32),
         chunk_agg_ldst=chunk_agg_ldst,
+        chunk_agg_btile=chunk_agg_btile,
     )
 
 

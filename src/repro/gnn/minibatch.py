@@ -43,7 +43,8 @@ Aggregation backend (`GNNSpec.agg_backend` in {scatter, tiled, pallas}): the
 forward pass aggregates each MFG layer through `kernels.ops.aggregate` —
 sums and GAT's stabilisation max alike. For the tiled/pallas backends the
 host sampler attaches a per-layer tiled edge layout
-(`SampledLayer.agg_order`/`agg_ldst`, sized by the static pad plan via
+(`SampledLayer.agg_order`/`agg_ldst`/`agg_btile`, sized by the static pad
+plan via
 `LayerPad.tiled_plan`) so the device step — compiled once — runs the
 pre-sorted segment-reduce instead of a data-dependent scatter; the sum's
 backward is a plain gather (custom_vjp in ops.py), so gradients match the
@@ -101,7 +102,8 @@ def step_donate_argnums(lossless: bool) -> tuple:
 
 # ---------------------------------------------------------------------------
 # Device-side mini-batch model (directed MFG layers + self connection).
-# `lay` = dict(esrc, edst, emask, deg, agg_order, agg_ldst); n_dst is static
+# `lay` = dict(esrc, edst, emask, deg, agg_order, agg_ldst, agg_btile); n_dst
+# is static
 # (from the pad plan). Aggregation targets are sized n_dst+1; index n_dst is
 # the padding sink. Every edge aggregation — the sums AND GAT's softmax
 # stabilisation max — goes through `ops.aggregate` (`backend` in {scatter,
@@ -119,6 +121,7 @@ def _mb_aggregate(messages, lay, n_dst: int, backend: str,
     return ops.aggregate(
         messages, lay["edst"], n_dst + 1,
         edge_order=lay.get("agg_order"), local_dst=lay.get("agg_ldst"),
+        block_tile=lay.get("agg_btile"),
         backend=backend, reduce=reduce,
     )
 

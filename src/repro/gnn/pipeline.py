@@ -236,6 +236,8 @@ class BatchPreparer:
                     [b.layers[li].agg_order for b in batches])
                 lay["agg_ldst"] = np.stack(
                     [b.layers[li].agg_ldst for b in batches])
+                lay["agg_btile"] = np.stack(
+                    [b.layers[li].agg_btile for b in batches])
         return stacked, fetch
 
     # -------------------------------------------------------------- prepare

@@ -43,7 +43,11 @@ class LayerPad(NamedTuple):
         """Static (n_tiles, per_tile) of this layer's tiled-aggregation
         layout. A row tile holds <= tile_v destination rows, each with at
         most `fanout` sampled in-edges, so per_tile is bounded without ever
-        looking at a concrete batch — the pad plan stays static."""
+        looking at a concrete batch — the pad plan stays static. The layout
+        keeps this forced uniform count rather than a ragged one: the
+        static ragged total, ceil(n_edges / block_e) + n_tiles blocks, adds
+        a block to every tile and is smaller only where the last tile is
+        mostly empty."""
         _, n_tiles = tiled_shape(self.n_dst + 1, tile_v)  # + padding sink row
         cap = min(self.n_edges, tile_v * fanout)
         per_tile = max(-(-cap // block_e), 1) * block_e
@@ -86,6 +90,7 @@ class SampledLayer(NamedTuple):
     # None unless the sampler was asked for it (tiled/pallas backends only).
     agg_order: Optional[np.ndarray] = None  # [E_tiled] int32 (pad -> n_edges)
     agg_ldst: Optional[np.ndarray] = None   # [E_tiled] int32 (pad -> tile_v)
+    agg_btile: Optional[np.ndarray] = None  # [E_tiled / block_e] int32
 
 
 class SampledBatch(NamedTuple):
@@ -148,8 +153,9 @@ def sample_blocks(
     """Sample a k-hop MFG stack for `seeds` (innermost hop first in output).
 
     `tiled_layout` additionally attaches the per-layer tiled aggregation
-    layout (agg_order/agg_ldst) — only the tiled/pallas backends read it, so
-    the default scatter path skips the extra host argsort per layer."""
+    layout (agg_order/agg_ldst/agg_btile) — only the tiled/pallas backends
+    read it, so the default scatter path skips the extra host argsort per
+    layer."""
     indptr, indices = graph.csr()
     fanouts = tuple(int(f) for f in fanouts)
 
@@ -194,10 +200,10 @@ def sample_blocks(
         emask[:n_e] = True
         deg = np.zeros(pad.n_dst + 1, dtype=np.float32)
         np.add.at(deg, dst_p, 1.0)
-        agg_order = agg_ldst = None
+        agg_order = agg_ldst = agg_btile = None
         if tiled_layout:
             _, per_tile = pad.tiled_plan(fanouts[i])
-            agg_order, agg_ldst, _ = prepare_tiled_edges(
+            agg_order, agg_ldst, agg_btile, _ = prepare_tiled_edges(
                 edst, pad.n_dst + 1, per_tile=per_tile, valid=emask,
             )
             agg_order = agg_order.astype(np.int32)
@@ -207,6 +213,7 @@ def sample_blocks(
                 n_dst=np.int32(dst_count), sampled_deg=deg,
                 agg_order=agg_order,
                 agg_ldst=agg_ldst,
+                agg_btile=agg_btile,
             )
         )
 

@@ -126,6 +126,7 @@ class Block(NamedTuple):
     # (kernels.ops.prepare_tiled_edges; used by the tiled/pallas backends)
     agg_order: jnp.ndarray   # [E_tiled] int32 (pad -> 2*Eloc)
     agg_ldst: jnp.ndarray    # [E_tiled] int32 (pad -> tile_v)
+    agg_btile: jnp.ndarray   # [E_tiled / block_e] int32 row tile per block
 
 
 def build_blocks(
@@ -160,6 +161,7 @@ def build_blocks(
         vglobal=jnp.asarray(vg.astype(np.int32)),
         agg_order=jnp.asarray(book.agg_order),
         agg_ldst=jnp.asarray(book.agg_ldst),
+        agg_btile=jnp.asarray(book.agg_btile),
     )
 
 
@@ -215,6 +217,7 @@ class _PartialAggSync(_CodecSync):
         agg = ops.aggregate(
             messages, dst, n,
             edge_order=blk.agg_order, local_dst=blk.agg_ldst,
+            block_tile=blk.agg_btile,
             backend=backend, reduce=reduce,
         )
         agg = self.reduce_max(agg) if reduce == "max" else self.reduce_sum(agg)
@@ -381,6 +384,7 @@ class RingBlock(NamedTuple):
     # per-chunk tiled layouts ([k, 0] when built without tiled_layout)
     chunk_agg_order: jnp.ndarray  # [k, E_tiled] int32
     chunk_agg_ldst: jnp.ndarray   # [k, E_tiled] int32
+    chunk_agg_btile: jnp.ndarray  # [k, E_tiled / block_e] int32
 
 
 def build_ring_blocks(
@@ -410,6 +414,7 @@ def build_ring_blocks(
         chunk_emask=jnp.asarray(book.chunk_emask),
         chunk_agg_order=jnp.asarray(book.chunk_agg_order),
         chunk_agg_ldst=jnp.asarray(book.chunk_agg_ldst),
+        chunk_agg_btile=jnp.asarray(book.chunk_agg_btile),
     )
 
 
@@ -471,6 +476,7 @@ class RingSync(_CodecSync):
                 messages, blk.chunk_edst[s], n,
                 edge_order=blk.chunk_agg_order[s] if tiled else None,
                 local_dst=blk.chunk_agg_ldst[s] if tiled else None,
+                block_tile=blk.chunk_agg_btile[s] if tiled else None,
                 backend=backend, reduce=reduce,
             )
             if acc is None:

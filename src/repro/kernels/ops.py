@@ -23,6 +23,7 @@ from repro.kernels.tiling import (  # noqa: F401 (re-exported host-layout API)
     DEFAULT_TILE_F,
     DEFAULT_TILE_V,
     prepare_tiled_edges,
+    tiled_need_blocks,
     tiled_need_per_tile,
     tiled_shape,
 )
@@ -50,6 +51,7 @@ def segment_spmm(
     local_dst: jnp.ndarray,
     num_rows: int,
     *,
+    block_tile: jnp.ndarray,
     combiner: str = "sum",
     tile_v: int = DEFAULT_TILE_V,
     block_e: int = DEFAULT_BLOCK_E,
@@ -58,32 +60,33 @@ def segment_spmm(
 ) -> jnp.ndarray:
     """Tiled segment-reduce (`combiner` in {"sum", "max"}; see
     kernels/segment_spmm.py for the combiner semantics — init 0 vs -inf,
-    MXU one-hot matmul vs VPU masked max). `messages`/`local_dst` must come
-    from a `prepare_tiled_edges` layout built with the SAME (tile_v,
-    block_e); non-TPU backends use the oracle. `num_rows` may be unpadded —
-    both paths derive the tile grid from `tiled_shape` and return
-    [num_rows, F]."""
+    MXU one-hot matmul vs VPU masked max). `messages`/`local_dst`/
+    `block_tile` must come from a `prepare_tiled_edges` layout built with
+    the SAME (tile_v, block_e); non-TPU backends use the oracle. `num_rows`
+    may be unpadded — both paths derive the tile grid from `tiled_shape` and
+    return [num_rows, F]."""
     # Derive the grid the layout was built with (the ONE padding rule,
     # tiling.tiled_shape) — floor-dividing num_rows here would mis-bin every
     # edge of the trailing tiles when num_rows is unpadded.
     e = messages.shape[0]
     rows_padded, n_tiles = tiled_shape(num_rows, tile_v)
-    assert e % n_tiles == 0, (
-        f"tiled layout mismatch: {e} edges do not split over {n_tiles} row "
-        f"tiles (num_rows={num_rows}, tile_v={tile_v}); was the layout built "
-        f"with a different (num_rows, tile_v)?")
+    n_blocks = block_tile.shape[0]
+    assert e == n_blocks * block_e and n_blocks >= n_tiles, (
+        f"tiled layout mismatch: {e} edges are not the {n_blocks} blocks of "
+        f"{block_e} that block_tile names, or too few blocks for {n_tiles} "
+        f"row tiles (num_rows={num_rows}, tile_v={tile_v}); was the layout "
+        f"built with a different (num_rows, tile_v, block_e)?")
     use = _on_tpu() if use_pallas is None else use_pallas
     if use or interpret:
         out = _spmm_pallas(
-            messages, local_dst, rows_padded,
+            messages, local_dst, block_tile, rows_padded,
             combiner=combiner, block_e=block_e, tile_v=tile_v,
             tile_f=_pick_tile_f(messages.shape[1]),
             interpret=interpret or not _on_tpu(),
         )
         return out[:num_rows]
     # oracle path: local_dst is tile-relative; rebuild global ids
-    per_tile = e // n_tiles
-    tile_idx = jnp.arange(e) // per_tile
+    tile_idx = block_tile[jnp.arange(e) // block_e]
     gdst = jnp.where(
         local_dst >= tile_v, num_rows, tile_idx * tile_v + local_dst
     ).astype(jnp.int32)
@@ -126,7 +129,7 @@ def scatter_free_traced(backend: str) -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
 def _tiled_aggregate(num_rows, tile_v, block_e, use_pallas, interpret,
-                     messages, dst, edge_order, local_dst):
+                     messages, dst, edge_order, local_dst, block_tile):
     """Tiled segment-sum of `messages` into `num_rows` rows.
 
     Forward runs the pre-sorted / pre-blocked layout (gather by `edge_order`,
@@ -141,16 +144,16 @@ def _tiled_aggregate(num_rows, tile_v, block_e, use_pallas, interpret,
         ordered = msg_pad[edge_order]
     with jax.named_scope("edge.reduce"):
         return segment_spmm(
-            ordered, local_dst, num_rows,
+            ordered, local_dst, num_rows, block_tile=block_tile,
             tile_v=tile_v, block_e=block_e,
             use_pallas=use_pallas, interpret=interpret,
         )
 
 
 def _tiled_aggregate_fwd(num_rows, tile_v, block_e, use_pallas, interpret,
-                         messages, dst, edge_order, local_dst):
+                         messages, dst, edge_order, local_dst, block_tile):
     out = _tiled_aggregate(num_rows, tile_v, block_e, use_pallas, interpret,
-                           messages, dst, edge_order, local_dst)
+                           messages, dst, edge_order, local_dst, block_tile)
     return out, dst
 
 
@@ -160,7 +163,7 @@ def _tiled_aggregate_bwd(num_rows, tile_v, block_e, use_pallas, interpret,
     # transpose of the pre-sorted scatter-add: a gather (pad dst -> zero row)
     g_pad = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)], axis=0)
     grad_messages = g_pad[jnp.minimum(dst, num_rows)]
-    return grad_messages, None, None, None
+    return grad_messages, None, None, None, None
 
 
 _tiled_aggregate.defvjp(_tiled_aggregate_fwd, _tiled_aggregate_bwd)
@@ -168,7 +171,7 @@ _tiled_aggregate.defvjp(_tiled_aggregate_fwd, _tiled_aggregate_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
 def _tiled_aggregate_max(num_rows, tile_v, block_e, use_pallas, interpret,
-                         messages, dst, edge_order, local_dst):
+                         messages, dst, edge_order, local_dst, block_tile):
     """Tiled segment-max of `messages` into `num_rows` rows.
 
     Forward runs the same pre-sorted / pre-blocked layout as the sum, with
@@ -194,23 +197,24 @@ def _tiled_aggregate_max(num_rows, tile_v, block_e, use_pallas, interpret,
         ordered = msg_pad[edge_order]
     with jax.named_scope("edge.reduce"):
         return segment_spmm(
-            ordered, local_dst, num_rows,
+            ordered, local_dst, num_rows, block_tile=block_tile,
             combiner="max", tile_v=tile_v, block_e=block_e,
             use_pallas=use_pallas, interpret=interpret,
         )
 
 
 def _tiled_aggregate_max_fwd(num_rows, tile_v, block_e, use_pallas, interpret,
-                             messages, dst, edge_order, local_dst):
+                             messages, dst, edge_order, local_dst, block_tile):
     out = _tiled_aggregate_max(num_rows, tile_v, block_e, use_pallas,
-                               interpret, messages, dst, edge_order, local_dst)
-    return out, (messages, dst, out, edge_order, local_dst)
+                               interpret, messages, dst, edge_order, local_dst,
+                               block_tile)
+    return out, (messages, dst, out, edge_order, local_dst, block_tile)
 
 
 @jax.named_scope("edge.reduce")
 def _tiled_aggregate_max_bwd(num_rows, tile_v, block_e, use_pallas, interpret,
                              res, g):
-    messages, dst, out, edge_order, local_dst = res
+    messages, dst, out, edge_order, local_dst, block_tile = res
     e, f = messages.shape
     dstc = jnp.minimum(dst, num_rows)
     # The forward maxes over the edges PRESENT in the layout; a dropped
@@ -227,11 +231,12 @@ def _tiled_aggregate_max_bwd(num_rows, tile_v, block_e, use_pallas, interpret,
     # even split among ties (the scatter oracle's subgradient convention):
     # per-row tie counts via the tiled segment-sum of the argmax mask
     ties = _tiled_aggregate(num_rows, tile_v, block_e, use_pallas, interpret,
-                            is_max.astype(g.dtype), dst, edge_order, local_dst)
+                            is_max.astype(g.dtype), dst, edge_order, local_dst,
+                            block_tile)
     ties_pad = jnp.concatenate([ties, jnp.ones((1, f), g.dtype)], axis=0)
     share = g_pad[dstc] / jnp.maximum(ties_pad[dstc], 1.0)
     grad_messages = jnp.where(is_max, share, 0.0).astype(messages.dtype)
-    return grad_messages, None, None, None
+    return grad_messages, None, None, None, None
 
 
 _tiled_aggregate_max.defvjp(_tiled_aggregate_max_fwd, _tiled_aggregate_max_bwd)
@@ -247,6 +252,7 @@ def aggregate(
     *,
     edge_order: jnp.ndarray | None = None,  # from prepare_tiled_edges
     local_dst: jnp.ndarray | None = None,
+    block_tile: jnp.ndarray | None = None,
     backend: str = "scatter",
     reduce: str = "sum",
     tile_v: int = DEFAULT_TILE_V,
@@ -258,9 +264,9 @@ def aggregate(
     backend:
       scatter — data-dependent `at[].add` / `at[].max` (the oracle; XLA
                 scatter)
-      tiled   — `prepare_tiled_edges` layout through the tiled segment-reduce
-                (jnp oracle off-TPU, Pallas kernel on TPU); custom_vjp
-                backward
+      tiled   — `prepare_tiled_edges` layout (edge_order, local_dst,
+                block_tile) through the tiled segment-reduce (jnp oracle
+                off-TPU, Pallas kernel on TPU); custom_vjp backward
       pallas  — like tiled but forces the Pallas kernel (interpreted on CPU;
                 tests use this)
 
@@ -305,7 +311,8 @@ def aggregate(
     if backend not in AGG_BACKENDS:
         raise ValueError(f"unknown aggregate backend {backend!r}; "
                          f"options: {AGG_BACKENDS}")
-    assert edge_order is not None and local_dst is not None, (
+    assert (edge_order is not None and local_dst is not None
+            and block_tile is not None), (
         "tiled/pallas backends need the prepare_tiled_edges layout")
     if edge_order.shape[-1] == 0 and messages.shape[0] > 0:
         raise ValueError(
@@ -315,7 +322,7 @@ def aggregate(
     fn = _tiled_aggregate_max if reduce == "max" else _tiled_aggregate
     return fn(
         num_rows, tile_v, block_e, use_pallas, interpret,
-        messages, dst, edge_order, local_dst,
+        messages, dst, edge_order, local_dst, block_tile,
     )
 
 

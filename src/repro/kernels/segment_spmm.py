@@ -5,12 +5,19 @@ partition-local edge list). TPU adaptation of the insight (DESIGN.md §2):
 data-dependent scatters are hostile to the MXU/VPU, but a scatter whose
 segment ids are PRE-SORTED and PRE-TILED becomes a dense tile operation. The
 host (partition book) sorts edges by destination once per graph and blocks
-them so one edge block touches one row tile:
+them so one edge block touches one row tile. Each row tile gets only the
+blocks its own edges fill (at least one), so the layout is ragged, and a
+scalar-prefetched map `block_tile[b]` names the row tile of edge block b:
 
-  grid = (row_tiles, feature_tiles, edge_blocks_per_tile)   # reduction last
+  grid = (feature_tiles, edge_blocks)            # reduction last
+  out block of step (ft, b) = (block_tile[b], ft)
   kernel: P[r, e] = one_hot(local_dst)          (VPU compare on iota)
   sum:    acc    += P @ messages                (MXU matmul)
   max:    acc     = max(acc, masked-max over edge chunks)   (VPU)
+
+A tile's blocks are consecutive, so its output block stays resident while
+they fold into it (as in a grouped matmul), and is initialised to the
+combiner identity at the tile's first block.
 
 The same one-hot layout serves both combiners; only the init value (0 vs
 -inf) and the accumulation differ. Max has no matmul form (it is a reduction
@@ -66,23 +73,26 @@ def _max_chunk_e(block_e: int, tile_v: int, tile_f: int) -> int:
     return chunk
 
 
-def _segment_reduce_kernel(dst_ref, msg_ref, out_ref, *scratch, block_e,
-                           tile_v, combiner, chunk_e):
+def _segment_reduce_kernel(tile_ref, dst_ref, msg_ref, out_ref, *scratch,
+                           block_e, tile_v, combiner, chunk_e):
     """One grid step: fold one edge block into its row tile.
 
+    tile_ref: [n_blocks]       int32 (SMEM, scalar-prefetched) — row tile
+                               of each edge block, non-decreasing
     dst_ref: [1, block_e]      int32 — LOCAL row ids within this row tile
                                (pad edges -> tile_v, i.e. out of range)
     msg_ref: [block_e, tile_f] message block
     out_ref: [tile_v, tile_f]  row-tile accumulator. The edge-block axis is
-                               the innermost grid axis, so the block stays
-                               resident across it; it is initialised at
-                               edge block 0 to the combiner identity (0 for
+                               the innermost grid axis and a tile's blocks
+                               are consecutive, so the block stays resident
+                               across them; it is initialised at the tile's
+                               first block to the combiner identity (0 for
                                sum, -inf for max)
     scratch: max only — [block_e, tile_f] int32 column copy of dst_ref
     """
-    eb = pl.program_id(2)
+    b = pl.program_id(1)
 
-    @pl.when(eb == 0)
+    @pl.when((b == 0) | (tile_ref[b] != tile_ref[jnp.maximum(b - 1, 0)]))
     def _init():
         if combiner == "sum":
             out_ref[...] = jnp.zeros_like(out_ref)
@@ -126,8 +136,9 @@ def _segment_reduce_kernel(dst_ref, msg_ref, out_ref, *scratch, block_e,
 
 
 def segment_spmm(
-    messages: jnp.ndarray,   # [E, F] edge messages, pre-sorted by dst tile
-    local_dst: jnp.ndarray,  # [E] int32 row id WITHIN the edge's row tile
+    messages: jnp.ndarray,    # [E, F] edge messages, pre-sorted by dst tile
+    local_dst: jnp.ndarray,   # [E] int32 row id WITHIN the edge's row tile
+    block_tile: jnp.ndarray,  # [E / block_e] int32 row tile of each block
     num_rows: int,
     *,
     combiner: str = "sum",
@@ -141,23 +152,23 @@ def segment_spmm(
     "max" (init -inf, VPU masked max). Rows no edge reaches come back as the
     combiner identity (0 / -inf).
 
-    E must be row-tile-blocked: edges of row tile r occupy the contiguous
-    range [r * epr, (r+1) * epr) where epr = E // num_row_tiles, padded with
-    local_dst == tile_v (an out-of-range row hits nothing under either
-    combiner). `prepare_tiled_edges` (ops.py) produces this layout from raw
-    (dst, msg).
+    E must be cut into blocks of block_e edges, each within one row tile:
+    block b holds edges of row tile block_tile[b], block_tile is
+    non-decreasing and names every row tile at least once, and pad edges
+    carry local_dst == tile_v (an out-of-range row hits nothing under either
+    combiner). `prepare_tiled_edges` (tiling.py) produces this layout from
+    raw (dst, msg).
     """
     assert combiner in COMBINERS, combiner
     e, f = messages.shape
     assert num_rows % tile_v == 0, (num_rows, tile_v)
     assert f % tile_f == 0, (f, tile_f)
-    n_tiles = num_rows // tile_v
-    assert e % (n_tiles * block_e) == 0, (e, n_tiles, block_e)
-    blocks_per_tile = e // n_tiles // block_e
+    n_blocks = block_tile.shape[0]
+    assert e == n_blocks * block_e, (e, n_blocks, block_e)
 
-    # reduction (edge-block) axis innermost: the output block (r, ft) stays
-    # resident while every edge block of row tile r folds into it
-    grid = (n_tiles, f // tile_f, blocks_per_tile)
+    # reduction (edge-block) axis innermost: the output block
+    # (block_tile[b], ft) stays resident while a tile's blocks fold into it
+    grid = (f // tile_f, n_blocks)
     kernel = functools.partial(
         _segment_reduce_kernel, block_e=block_e, tile_v=tile_v,
         combiner=combiner, chunk_e=_max_chunk_e(block_e, tile_v, tile_f),
@@ -166,23 +177,24 @@ def segment_spmm(
                if combiner == "max" else [])
     # [E] -> [E / block_e, 1, block_e]: a 1-D int32 block does not match
     # the TPU's HBM tiling; a (1, block_e) trailing block does
-    dst_blocks = local_dst.reshape(e // block_e, 1, block_e)
-    return pl.pallas_call(
-        kernel,
+    dst_blocks = local_dst.reshape(n_blocks, 1, block_e)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, 1, block_e),
-                         lambda r, ft, eb: (r * blocks_per_tile + eb, 0, 0)),
-            pl.BlockSpec(
-                (block_e, tile_f),
-                lambda r, ft, eb: (r * blocks_per_tile + eb, ft),
-            ),
+            pl.BlockSpec((None, 1, block_e), lambda ft, b, tiles: (b, 0, 0)),
+            pl.BlockSpec((block_e, tile_f), lambda ft, b, tiles: (b, ft)),
         ],
-        out_specs=pl.BlockSpec((tile_v, tile_f), lambda r, ft, eb: (r, ft)),
-        out_shape=jax.ShapeDtypeStruct((num_rows, f), messages.dtype),
+        out_specs=pl.BlockSpec((tile_v, tile_f),
+                               lambda ft, b, tiles: (tiles[b], ft)),
         scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((num_rows, f), messages.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=f"segment_spmm_{combiner}",
-    )(dst_blocks, messages)
+    )(block_tile.astype(jnp.int32), dst_blocks, messages)

@@ -40,6 +40,29 @@ def _check_dst_range(vdst: np.ndarray, num_rows: int, rows_padded: int) -> None:
         )
 
 
+
+
+def _tile_blocks(
+    vdst: np.ndarray,
+    num_rows: int,
+    tile_v: int,
+    block_e: int,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(row tile of each edge, edges of each row tile, edge blocks each row
+    tile needs) for the valid destinations `vdst`: a tile needs its edge
+    count rounded up to whole blocks, and at least one, so that the kernel
+    writes every row tile."""
+    rows_padded, n_tiles = tiled_shape(num_rows, tile_v)
+    _check_dst_range(vdst, num_rows, rows_padded)
+    tile_of = vdst // tile_v
+    counts = np.bincount(tile_of, minlength=n_tiles)
+    return tile_of, counts, np.maximum(-(-counts // block_e), 1)
+
+
+def _valid_dst(dst: np.ndarray, valid: np.ndarray | None) -> np.ndarray:
+    return np.asarray(dst if valid is None else dst[valid], dtype=np.int64)
+
+
 def tiled_need_per_tile(
     dst: np.ndarray,
     num_rows: int,
@@ -48,14 +71,24 @@ def tiled_need_per_tile(
     block_e: int = DEFAULT_BLOCK_E,
     valid: np.ndarray | None = None,
 ) -> int:
-    """Smallest legal `per_tile` for this edge list — the block-rounded max
-    per-tile edge count — without building the layout (O(E) bincount)."""
-    rows_padded, n_tiles = tiled_shape(num_rows, tile_v)
-    vdst = np.asarray(dst if valid is None else dst[valid], dtype=np.int64)
-    _check_dst_range(vdst, num_rows, rows_padded)
-    counts = np.bincount(vdst // tile_v, minlength=n_tiles)
-    blocks = int(np.ceil(counts.max() / block_e)) if counts.size else 0
-    return max(blocks, 1) * block_e
+    """Smallest legal forced `per_tile` for this edge list — the block-rounded
+    max per-tile edge count — without building the layout (O(E) bincount)."""
+    blocks = _tile_blocks(_valid_dst(dst, valid), num_rows, tile_v, block_e)[2]
+    return int(blocks.max()) * block_e
+
+
+def tiled_need_blocks(
+    dst: np.ndarray,
+    num_rows: int,
+    *,
+    tile_v: int = DEFAULT_TILE_V,
+    block_e: int = DEFAULT_BLOCK_E,
+    valid: np.ndarray | None = None,
+) -> int:
+    """Edge blocks of this edge list's ragged layout — the smallest legal
+    `n_blocks` — without building the layout (O(E) bincount)."""
+    blocks = _tile_blocks(_valid_dst(dst, valid), num_rows, tile_v, block_e)[2]
+    return int(blocks.sum())
 
 
 def prepare_tiled_edges(
@@ -65,54 +98,68 @@ def prepare_tiled_edges(
     tile_v: int = DEFAULT_TILE_V,
     block_e: int = DEFAULT_BLOCK_E,
     per_tile: int | None = None,
+    n_blocks: int | None = None,
     valid: np.ndarray | None = None,
-) -> tuple[np.ndarray, np.ndarray, int]:
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
     """Host-side layout pass (once per graph/partition): sort edges by row
-    tile and pad each tile's edge list to a multiple of block_e.
+    tile and give each tile the edge blocks it needs.
 
-    Returns (edge_order, local_dst, rows_padded):
-      edge_order [E_padded] — gather indices into the original edge list
-                              (padding -> E, caller appends a zero message row)
-      local_dst  [E_padded] — row id within the edge's tile (padding -> tile_v)
+    Row tile t gets max(ceil(count_t / block_e), 1) blocks of block_e edges,
+    so a tile is padded to its own edge count, not to the busiest tile's: on
+    a graph whose degrees are skewed across row tiles the layout stays within
+    one block a tile of the real messages. Blocks lie in tile order, and
+    edges keep their input order within a tile. Every tile gets at least one
+    block, so the kernel writes every row tile: rows no edge reaches come
+    back as the combiner's identity.
+
+    Returns (edge_order, local_dst, block_tile, rows_padded):
+      edge_order [E_tiled]  — gather indices into the original edge list
+                              (padding -> E, caller appends an identity row)
+      local_dst  [E_tiled]  — row id within the edge's tile (padding -> tile_v)
+      block_tile [n_blocks] — int32 row tile of each edge block,
+                              non-decreasing; E_tiled = n_blocks * block_e
 
     `valid` (bool[E]) drops edges from the layout entirely; only edges whose
     messages carry the combiner identity (zero for sum, <= any real score for
     max) may be dropped (the aggregate stays exact). `per_tile` forces every
-    tile's padded edge count, so several partitions / batches can share one
-    static device shape; it must be a multiple of block_e and at least the
-    largest per-tile edge count (`tiled_need_per_tile`).
+    tile's padded edge count instead (then block_tile[b] = b // (per_tile //
+    block_e)); it must be a multiple of block_e and at least the largest
+    per-tile edge count (`tiled_need_per_tile`). `n_blocks` forces the total
+    block count, so several partitions / chunks share one static device
+    shape; it must be at least what the tiles need (`tiled_need_blocks`).
+    The blocks past that need are trailing pad blocks: block_tile =
+    n_tiles - 1 and local_dst = tile_v, so they reach no row.
 
     Every valid dst must lie in [0, rows_padded) — anything past the padded
     row range raises ValueError rather than silently vanishing from the
     aggregate (its row tile would sit outside the kernel grid).
     """
     e = dst.shape[0]
-    rows_padded, n_tiles = tiled_shape(num_rows, tile_v)
-    if valid is None:
-        idx = np.arange(e, dtype=np.int64)
-        vdst = np.asarray(dst, dtype=np.int64)
-    else:
-        idx = np.where(valid)[0].astype(np.int64)
-        vdst = np.asarray(dst, dtype=np.int64)[idx]
-    _check_dst_range(vdst, num_rows, rows_padded)
-    tile_of = vdst // tile_v
-    order = np.argsort(tile_of, kind="stable")
-    counts = np.bincount(tile_of, minlength=n_tiles)
-    # every tile gets the same number of edge blocks (grid uniformity)
-    need = int(max(int(np.ceil(counts.max() / block_e)) if counts.size else 0, 1))
-    need *= block_e
-    if per_tile is None:
-        per_tile = need
-    else:
+    idx = np.arange(e) if valid is None else np.flatnonzero(valid)
+    vdst = np.asarray(dst, dtype=np.int64)[idx]
+    tile_of, counts, blocks = _tile_blocks(vdst, num_rows, tile_v, block_e)
+    n_tiles = counts.shape[0]
+    if per_tile is not None:
+        need = int(blocks.max()) * block_e
         assert per_tile % block_e == 0 and per_tile >= need, (per_tile, need)
-    total = per_tile * n_tiles
-    edge_order = np.full(total, e, dtype=np.int64)
-    local_dst = np.full(total, tile_v, dtype=np.int32)
-    starts = np.cumsum(counts) - counts
-    for t in range(n_tiles):
-        seg = order[starts[t]: starts[t] + counts[t]]
-        edge_order[t * per_tile: t * per_tile + counts[t]] = idx[seg]
-        local_dst[t * per_tile: t * per_tile + counts[t]] = (
-            vdst[seg] - t * tile_v
-        ).astype(np.int32)
-    return edge_order, local_dst, rows_padded
+        blocks = np.full(n_tiles, per_tile // block_e)
+    used = int(blocks.sum())
+    if n_blocks is None:
+        n_blocks = used
+    assert n_blocks >= used, (n_blocks, used)
+    block_tile = np.full(n_blocks, n_tiles - 1, dtype=np.int32)
+    block_tile[:used] = np.repeat(np.arange(n_tiles, dtype=np.int32), blocks)
+
+    order = np.argsort(tile_of, kind="stable")
+    tile_sorted = tile_of[order]
+    # slot of the i-th sorted edge: its tile's first slot + its rank there
+    first_slot = (np.cumsum(blocks) - blocks) * block_e
+    first_edge = np.cumsum(counts) - counts
+    slot = (first_slot[tile_sorted] + np.arange(order.size)
+            - first_edge[tile_sorted])
+    edge_order = np.full(n_blocks * block_e, e, dtype=np.int64)
+    local_dst = np.full(n_blocks * block_e, tile_v, dtype=np.int32)
+    edge_order[slot] = idx[order]
+    local_dst[slot] = vdst[order] - tile_sorted * tile_v
+    rows_padded = n_tiles * tile_v
+    return edge_order, local_dst, block_tile, rows_padded

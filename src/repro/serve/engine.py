@@ -102,6 +102,7 @@ class ServeEngine:
             if lay.agg_order is not None:
                 d["agg_order"] = jnp.asarray(lay.agg_order)
                 d["agg_ldst"] = jnp.asarray(lay.agg_ldst)
+                d["agg_btile"] = jnp.asarray(lay.agg_btile)
             layers.append(d)
         return {"x": jnp.asarray(x), "layers": layers}
 

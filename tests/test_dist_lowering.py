@@ -154,24 +154,24 @@ def test_segment_max_tiled_under_shard_map():
                                         rng.integers(0, v, e - v)])
                         for _ in range(k)]).astype(np.int32)
         msgs = rng.normal(size=(k, e, f)).astype(np.float32)
-        per_tile = max(ops.prepare_tiled_edges(dst[p], v)[0].shape[0]
-                       for p in range(k)) // ops.tiled_shape(v)[1]
-        lay = [ops.prepare_tiled_edges(dst[p], v, per_tile=per_tile)[:2]
+        n_blocks = max(ops.tiled_need_blocks(dst[p], v) for p in range(k))
+        lay = [ops.prepare_tiled_edges(dst[p], v, n_blocks=n_blocks)[:3]
                for p in range(k)]
-        order = np.stack([o for o, _ in lay])
-        ldst = np.stack([l for _, l in lay])
+        order, ldst, btile = (np.stack(a) for a in zip(*lay))
         mesh = make_mesh((k,), ("parts",))
 
-        def per_device(m, d, o, l):
+        def per_device(m, d, o, l, t):
             out = ops.aggregate(m[0], d[0], v, edge_order=o[0],
-                                local_dst=l[0], backend="tiled", reduce="max")
+                                local_dst=l[0], block_tile=t[0],
+                                backend="tiled", reduce="max")
             return out[None]
 
         fn = jax.shard_map(per_device, mesh=mesh,
-                           in_specs=(P("parts"),) * 4, out_specs=P("parts"),
+                           in_specs=(P("parts"),) * 5, out_specs=P("parts"),
                            check_vma=False)
         got = jax.jit(fn)(jnp.asarray(msgs), jnp.asarray(dst),
-                          jnp.asarray(order), jnp.asarray(ldst))
+                          jnp.asarray(order), jnp.asarray(ldst),
+                          jnp.asarray(btile))
         expect = jax.vmap(lambda m, d: ops.aggregate(
             m, d, v, backend="scatter", reduce="max"))(
             jnp.asarray(msgs), jnp.asarray(dst))

@@ -16,11 +16,11 @@ def test_segment_spmm_sweep(e, v, f, dtype):
     rng = np.random.default_rng(e + v + f)
     dst = rng.integers(0, v, e).astype(np.int32)
     msgs = rng.normal(size=(e, f)).astype(np.float32)
-    order, local_dst, rows_p = ops.prepare_tiled_edges(dst, v)
+    order, local_dst, btile, rows_p = ops.prepare_tiled_edges(dst, v)
     msgs_pad = np.concatenate([msgs, np.zeros((1, f), np.float32)])[order]
     out = ops.segment_spmm(
         jnp.asarray(msgs_pad, dtype), jnp.asarray(local_dst), rows_p,
-        interpret=True,
+        block_tile=jnp.asarray(btile), interpret=True,
     )
     expect = ref.segment_sum_ref(jnp.asarray(msgs, dtype), jnp.asarray(dst), v)
     tol = 1e-5 if dtype == np.float32 else 2e-2
@@ -40,14 +40,14 @@ def test_segment_reduce_max_sweep(e, v, f, dtype):
     rng = np.random.default_rng(e + v + f)
     dst = rng.integers(0, v, e).astype(np.int32)
     msgs = rng.normal(size=(e, f)).astype(np.float32)
-    order, local_dst, rows_p = ops.prepare_tiled_edges(dst, v)
+    order, local_dst, btile, rows_p = ops.prepare_tiled_edges(dst, v)
     msgs_pad = np.concatenate([msgs, np.full((1, f), -np.inf, np.float32)])[order]
     expect = ref.segment_max_ref(jnp.asarray(msgs, dtype), jnp.asarray(dst), v)
     tol = 1e-6 if dtype == np.float32 else 2e-2
     for kw in ({"use_pallas": False}, {"interpret": True}):
         out = ops.segment_spmm(
             jnp.asarray(msgs_pad, dtype), jnp.asarray(local_dst), rows_p,
-            combiner="max", **kw)
+            block_tile=jnp.asarray(btile), combiner="max", **kw)
         np.testing.assert_allclose(
             np.asarray(out[:v], np.float32), np.asarray(expect, np.float32),
             rtol=tol, atol=tol * 8,
@@ -64,16 +64,16 @@ def test_segment_reduce_paper_width_multi_block(combiner):
     e = 2 * 3 * ops.DEFAULT_BLOCK_E - 100   # 2 row tiles, 3 blocks each
     dst = rng.integers(0, v, e).astype(np.int32)
     msgs = rng.normal(size=(e, f)).astype(np.float32)
-    order, local_dst, rows_p = ops.prepare_tiled_edges(dst, v)
-    n_tiles = rows_p // ops.DEFAULT_TILE_V
-    assert order.shape[0] // n_tiles // ops.DEFAULT_BLOCK_E >= 2
+    order, local_dst, btile, rows_p = ops.prepare_tiled_edges(dst, v)
+    assert np.bincount(btile).min() >= 2
     assert f // ops._pick_tile_f(f) == 4
     fill = 0.0 if combiner == "sum" else -np.inf
     msgs_pad = np.concatenate([msgs, np.full((1, f), fill, np.float32)])[order]
     ref_fn = ref.segment_sum_ref if combiner == "sum" else ref.segment_max_ref
     expect = ref_fn(jnp.asarray(msgs), jnp.asarray(dst), v)
     out = ops.segment_spmm(jnp.asarray(msgs_pad), jnp.asarray(local_dst),
-                           rows_p, combiner=combiner, interpret=True)
+                           rows_p, block_tile=jnp.asarray(btile),
+                           combiner=combiner, interpret=True)
     np.testing.assert_allclose(np.asarray(out[:v]), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
 
@@ -88,7 +88,7 @@ def test_segment_spmm_oracle_unpadded_num_rows(combiner):
     e, v, f = 900, 300, 8  # 300 rows -> 2 tiles of 256; 300 // 256 == 1
     dst = rng.integers(0, v, e).astype(np.int32)
     msgs = rng.normal(size=(e, f)).astype(np.float32)
-    order, local_dst, _ = ops.prepare_tiled_edges(dst, v)
+    order, local_dst, btile, _ = ops.prepare_tiled_edges(dst, v)
     fill = 0.0 if combiner == "sum" else -np.inf
     msgs_pad = np.concatenate([msgs, np.full((1, f), fill, np.float32)])[order]
     ref_fn = ref.segment_sum_ref if combiner == "sum" else ref.segment_max_ref
@@ -96,22 +96,30 @@ def test_segment_spmm_oracle_unpadded_num_rows(combiner):
     for kw in ({"use_pallas": False}, {"interpret": True}):
         out = ops.segment_spmm(
             jnp.asarray(msgs_pad), jnp.asarray(local_dst), v,  # unpadded!
-            combiner=combiner, **kw)
+            block_tile=jnp.asarray(btile), combiner=combiner, **kw)
         assert out.shape == (v, f)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                    rtol=1e-5, atol=1e-5)
 
 
 def test_segment_spmm_layout_mismatch_fails_loudly():
-    """An edge count that cannot split over the tile grid (layout built for
-    a different num_rows/tile_v) must assert, not mis-bin silently."""
-    msgs = jnp.zeros((3, 8), jnp.float32)  # 3 edges over 2 tiles of v=300
+    """An edge count that is not the blocks `block_tile` names, or too few
+    blocks for the tile grid (layout built for a different num_rows/tile_v),
+    must assert, not mis-bin silently."""
+    msgs = jnp.zeros((3, 8), jnp.float32)  # 3 edges, not one block of 512
     local_dst = jnp.zeros((3,), jnp.int32)
     with pytest.raises(AssertionError, match="tiled layout mismatch"):
-        ops.segment_spmm(msgs, local_dst, 300, use_pallas=False)
+        ops.segment_spmm(msgs, local_dst, 300, use_pallas=False,
+                         block_tile=jnp.zeros((1,), jnp.int32))
+    msgs = jnp.zeros((ops.DEFAULT_BLOCK_E, 8), jnp.float32)  # 1 block, 2 tiles
+    local_dst = jnp.zeros((ops.DEFAULT_BLOCK_E,), jnp.int32)
+    with pytest.raises(AssertionError, match="tiled layout mismatch"):
+        ops.segment_spmm(msgs, local_dst, 300, use_pallas=False,
+                         block_tile=jnp.zeros((1,), jnp.int32))
 
 
-@pytest.mark.parametrize("fn", ["prepare_tiled_edges", "tiled_need_per_tile"])
+@pytest.mark.parametrize("fn", ["prepare_tiled_edges", "tiled_need_per_tile",
+                                "tiled_need_blocks"])
 def test_tiled_layout_rejects_out_of_range_dst(fn):
     """Regression: dst >= rows_padded grew the bincount past n_tiles and the
     trailing tiles' edges silently vanished from the aggregate. Both layout
@@ -138,14 +146,15 @@ def test_segment_spmm_nondefault_tiling(tile_v, block_e):
     e, v, f = 900, 700, 32
     dst = rng.integers(0, v, e).astype(np.int32)
     msgs = rng.normal(size=(e, f)).astype(np.float32)
-    order, local_dst, rows_p = ops.prepare_tiled_edges(
+    order, local_dst, btile, rows_p = ops.prepare_tiled_edges(
         dst, v, tile_v=tile_v, block_e=block_e)
     msgs_pad = np.concatenate([msgs, np.zeros((1, f), np.float32)])[order]
     expect = ref.segment_sum_ref(jnp.asarray(msgs), jnp.asarray(dst), v)
     for kw in ({"use_pallas": False}, {"interpret": True}):
         out = ops.segment_spmm(
             jnp.asarray(msgs_pad), jnp.asarray(local_dst), rows_p,
-            tile_v=tile_v, block_e=block_e, **kw)
+            block_tile=jnp.asarray(btile), tile_v=tile_v, block_e=block_e,
+            **kw)
         np.testing.assert_allclose(np.asarray(out[:v]), np.asarray(expect),
                                    rtol=1e-5, atol=1e-5)
 
@@ -166,15 +175,17 @@ def test_prepare_tiled_edges_ragged(case):
         v, e = 7, 40  # num_rows < tile_v
         dst = rng.integers(0, v, e).astype(np.int32)
     msgs = rng.normal(size=(e, f)).astype(np.float32)
-    order, local_dst, rows_p = ops.prepare_tiled_edges(dst, v)
+    order, local_dst, btile, rows_p = ops.prepare_tiled_edges(dst, v)
     assert rows_p % ops.DEFAULT_TILE_V == 0 and rows_p >= v
     assert order.shape == local_dst.shape
+    assert order.shape[0] == btile.shape[0] * ops.DEFAULT_BLOCK_E
     assert (local_dst <= ops.DEFAULT_TILE_V).all()
     msgs_pad = np.concatenate([msgs, np.zeros((1, f), np.float32)])[order]
     expect = ref.segment_sum_ref(jnp.asarray(msgs), jnp.asarray(dst), v)
     for kw in ({"use_pallas": False}, {"interpret": True}):
         out = ops.segment_spmm(
-            jnp.asarray(msgs_pad), jnp.asarray(local_dst), rows_p, **kw)
+            jnp.asarray(msgs_pad), jnp.asarray(local_dst), rows_p,
+            block_tile=jnp.asarray(btile), **kw)
         np.testing.assert_allclose(np.asarray(out[:v]), np.asarray(expect),
                                    rtol=1e-5, atol=1e-5)
 
@@ -186,7 +197,7 @@ def test_prepare_tiled_edges_valid_mask_and_per_tile():
     v, e = 300, 400
     dst = rng.integers(0, v, e).astype(np.int32)
     valid = rng.random(e) < 0.5
-    order, local_dst, rows_p = ops.prepare_tiled_edges(
+    order, local_dst, btile, rows_p = ops.prepare_tiled_edges(
         dst, v, per_tile=1024, valid=valid)
     n_tiles = rows_p // ops.DEFAULT_TILE_V
     assert order.shape[0] == n_tiles * 1024
@@ -196,11 +207,66 @@ def test_prepare_tiled_edges_valid_mask_and_per_tile():
     msgs_pad = np.concatenate([msgs, np.zeros((1, 8), np.float32)])[order]
     out = ops.segment_spmm(
         jnp.asarray(msgs_pad), jnp.asarray(local_dst), rows_p,
-        use_pallas=False)
+        block_tile=jnp.asarray(btile), use_pallas=False)
     expect = ref.segment_sum_ref(
         jnp.asarray(msgs * valid[:, None]), jnp.asarray(dst), v)
     np.testing.assert_allclose(np.asarray(out[:v]), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
+
+
+def _skewed_dst(rng):
+    """Edges over 4 row tiles of 256: a heavy tile 0 (3 blocks of 512), a
+    tile 1 that no edge reaches, and two light tiles."""
+    return rng.permutation(np.concatenate([
+        rng.integers(0, 256, 1400), rng.integers(512, 768, 100),
+        rng.integers(768, 1024, 300)])).astype(np.int32)
+
+
+def _reduce_layout(msgs, dst, v, combiner, **kw):
+    """Run `segment_spmm` (interpret) over a `prepare_tiled_edges` layout."""
+    order, ldst, btile, rows_p = ops.prepare_tiled_edges(dst, v, **kw)
+    fill = 0.0 if combiner == "sum" else -np.inf
+    f = msgs.shape[1]
+    msgs_pad = np.concatenate([msgs, np.full((1, f), fill, np.float32)])
+    out = ops.segment_spmm(
+        jnp.asarray(msgs_pad[order]), jnp.asarray(ldst), rows_p,
+        block_tile=jnp.asarray(btile), combiner=combiner, interpret=True)
+    return np.asarray(out[:v]), btile
+
+
+@pytest.mark.parametrize("f", [4, 128, 512])
+@pytest.mark.parametrize("combiner", ["sum", "max"])
+def test_segment_reduce_skewed_ragged_layout(combiner, f):
+    """The ragged grid on a skewed layout: one heavy tile whose output block
+    folds three edge blocks, one tile no edge reaches (its rows come back as
+    the combiner identity), and trailing pad blocks from a forced total."""
+    rng = np.random.default_rng(f)
+    v = 1024
+    dst = _skewed_dst(rng)
+    msgs = rng.normal(size=(dst.shape[0], f)).astype(np.float32)
+    out, btile = _reduce_layout(msgs, dst, v, combiner, n_blocks=8)
+    assert btile.tolist() == [0, 0, 0, 1, 2, 3, 3, 3]
+    ref_fn = ref.segment_sum_ref if combiner == "sum" else ref.segment_max_ref
+    expect = np.asarray(ref_fn(jnp.asarray(msgs), jnp.asarray(dst), v))
+    assert (expect[256:512] == (0.0 if combiner == "sum" else -np.inf)).all()
+    np.testing.assert_allclose(out, expect, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("f", [4, 128, 512])
+@pytest.mark.parametrize("combiner", ["sum", "max"])
+def test_segment_reduce_ragged_equals_uniform(combiner, f):
+    """The ragged layout drops only padding blocks, and each tile keeps its
+    edges in order and its block boundaries, so it reduces bit for bit
+    like the uniform layout of the same graph."""
+    rng = np.random.default_rng(f + 1)
+    v = 1024
+    dst = _skewed_dst(rng)
+    msgs = rng.normal(size=(dst.shape[0], f)).astype(np.float32)
+    ragged, r_tiles = _reduce_layout(msgs, dst, v, combiner)
+    uniform, u_tiles = _reduce_layout(
+        msgs, dst, v, combiner, per_tile=ops.tiled_need_per_tile(dst, v))
+    assert r_tiles.shape[0] == 6 and u_tiles.shape[0] == 12
+    np.testing.assert_array_equal(ragged, uniform)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])

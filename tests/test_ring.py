@@ -24,7 +24,11 @@ from repro.gnn.sync import (
     ring_bytes_per_round,
     sync_bytes_per_round,
 )
-from repro.kernels.tiling import prepare_tiled_edges, tiled_shape
+from repro.kernels.tiling import (
+    DEFAULT_BLOCK_E,
+    prepare_tiled_edges,
+    tiled_shape,
+)
 
 
 # ---------------------------------------------------------------------------
@@ -82,21 +86,22 @@ def test_blockrow_chunk_edges_sum_to_E(or_graph, k):
 @pytest.mark.parametrize("k", [1, 4])
 def test_blockrow_tiled_layouts_roundtrip(or_graph, k):
     """Per-chunk tiled layouts agree with a fresh `prepare_tiled_edges` pass
-    over the same chunk (validation round-trip), with ONE uniform per_tile
+    over the same chunk (validation round-trip), with ONE shared block count
     so the stacked [k, k, ...] arrays have a static shape."""
     book = build_blockrow_book(or_graph, k, tiled_layout=True)
     n_rows = book.v_block + 1
     _, n_tiles = tiled_shape(n_rows)
-    e_tiled = book.chunk_agg_order.shape[-1]
-    assert e_tiled % n_tiles == 0
-    per_tile = e_tiled // n_tiles
+    n_blocks = book.chunk_agg_btile.shape[-1]
+    assert book.chunk_agg_order.shape[-1] == n_blocks * DEFAULT_BLOCK_E
+    assert n_blocks >= n_tiles
     for p in range(k):
         for s in range(k):
-            order, ldst, rows_padded = prepare_tiled_edges(
-                book.chunk_edst[p, s], n_rows, per_tile=per_tile,
+            order, ldst, btile, rows_padded = prepare_tiled_edges(
+                book.chunk_edst[p, s], n_rows, n_blocks=n_blocks,
                 valid=book.chunk_emask[p, s])
             np.testing.assert_array_equal(book.chunk_agg_order[p, s], order)
             np.testing.assert_array_equal(book.chunk_agg_ldst[p, s], ldst)
+            np.testing.assert_array_equal(book.chunk_agg_btile[p, s], btile)
 
 
 def test_blockrow_partitioner_registered(or_graph):

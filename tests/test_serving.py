@@ -154,7 +154,8 @@ def test_batcher_static_shapes(node_setup):
         mfg = b.build_mfg(ids)
         sig = (mfg.input_ids.shape, tuple(
             (l.esrc.shape, l.edst.shape, l.emask.shape,
-             l.sampled_deg.shape, l.agg_order.shape, l.agg_ldst.shape)
+             l.sampled_deg.shape, l.agg_order.shape, l.agg_ldst.shape,
+             l.agg_btile.shape)
             for l in mfg.layers), mfg.seed_labels.shape)
         shapes.add(sig)
         assert int(mfg.seed_mask.sum()) == ids.shape[0]

@@ -74,15 +74,18 @@ def _shapes(tree, sharding):
 def test_segment_spmm_compiles_for_v5e(one_chip, kernel_dispatch, combiner,
                                        f):
     """The paper width (F=512, 4 feature tiles) and GAT's 4-head score
-    aggregate, with 3 edge blocks per row tile: the grid revisits each
-    output block across the reduction axis."""
-    n_tiles, blocks_per_tile = 2, 3
+    aggregate on a ragged layout of 3 row tiles in 6 edge blocks: the
+    scalar-prefetched block_tile map picks each block's output block, and
+    the grid revisits it across a tile's consecutive blocks."""
+    n_tiles, n_blocks = 3, 6
     rows = n_tiles * ops.DEFAULT_TILE_V
-    e = rows // ops.DEFAULT_TILE_V * blocks_per_tile * ops.DEFAULT_BLOCK_E
-    fn = jax.jit(lambda m, d: ops.segment_spmm(m, d, rows, combiner=combiner))
+    e = n_blocks * ops.DEFAULT_BLOCK_E
+    fn = jax.jit(lambda m, d, t: ops.segment_spmm(
+        m, d, rows, block_tile=t, combiner=combiner))
     compiled = fn.lower(
         jax.ShapeDtypeStruct((e, f), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((e,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n_blocks,), jnp.int32, sharding=one_chip),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
