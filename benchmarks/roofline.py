@@ -36,12 +36,10 @@ def _agg_traffic_bytes(book, spec, backend) -> str:
     message bytes streamed through the aggregation. The scatter backend
     reads/writes the raw symmetrised edge list; the tiled backend streams
     the blocked layout (real edges + tile padding; its book carries the
-    layout — the scatter book is built without one). sage/gcn stream one
-    [E, hidden] sum per layer; gat streams two [E, heads] score reduces
-    (segment-max + den sum) plus the [E, hidden] num sum."""
+    layout — the scatter book is built without one). Every model streams
+    one [E, hidden] pass per layer (gat's edge softmax scores its source
+    rows as it reads them)."""
     width = spec.hidden_dim
-    if spec.model == "gat":
-        width += 2 * spec.gat_heads
     e2 = 2 * int(book.emask.sum())          # real symmetrised edges
     if backend == "scatter":
         return f"agg_bytes={spec.num_layers * 2 * e2 * width * 4}"

@@ -13,7 +13,7 @@ One chip, each phase a few steps at the paper grid's widths (F = H = 512,
           tile, against the scatter oracle on the same chip
   a       full-batch sage, k = 1, tiled backend (the Pallas kernel), against
           the same step on the scatter backend
-  b       full-batch GAT (4 heads), tiled: the max combiner, against scatter
+  b       full-batch GAT (4 heads), tiled: the softmax combiner, against scatter
   c       mini-batch sage, k = 4 simulated, metis, overlapped pipeline: the
           step that donates its parameter buffers
   cli     one in-process `gnn_train.main` call with a short argv

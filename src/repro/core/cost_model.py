@@ -178,18 +178,17 @@ def _ring_epoch(
     compute = agg_bytes / cluster.mem_bw + nn_flops / cluster.flops
 
     dims = [dout for _, dout in spec.dims()]
-    aggs_per_layer = 3 if spec.model == "gat" else 1
-    syncs = aggs_per_layer * 2  # per layer, fwd+bwd
+    syncs = 2  # per layer, fwd+bwd: one rotated payload (GAT: its z)
     stage_rows = float(book.v_block + 1)
     comm_bytes = np.full(k, (k - 1) * stage_rows * 4 * sum(dims) * syncs)
     wire_bytes = np.zeros(k)
     for li, d in enumerate(dims):
-        eb = _wire_elem(codec, li * aggs_per_layer)
+        eb = _wire_elem(codec, li)
         wire_bytes += (k - 1) * stage_rows * eb * d * syncs
     comm = np.zeros(k)
     if k > 1:
         for li, d in enumerate(dims):
-            eb = _wire_elem(codec, li * aggs_per_layer)
+            eb = _wire_elem(codec, li)
             t_stage = (stage_rows * d * eb / cluster.net_bw
                        + cluster.net_latency)
             # per-stage chunk compute: this layer's aggregation share of the

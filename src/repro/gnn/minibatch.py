@@ -40,15 +40,16 @@ adjacency costs for ALL remote vertices because the cache holds features,
 not adjacency.
 
 Aggregation backend (`GNNSpec.agg_backend` in {scatter, tiled, pallas}): the
-forward pass aggregates each MFG layer through `kernels.ops.aggregate` —
-sums and GAT's stabilisation max alike. For the tiled/pallas backends the
+forward pass aggregates each MFG layer through `kernels.ops.aggregate`
+(sums) or `kernels.ops.edge_softmax` (GAT's attention). For the tiled/pallas backends the
 host sampler attaches a per-layer tiled edge layout
 (`SampledLayer.agg_order`/`agg_ldst`/`agg_btile`, sized by the static pad
 plan via
 `LayerPad.tiled_plan`) so the device step — compiled once — runs the
 pre-sorted segment-reduce instead of a data-dependent scatter; the sum's
 backward is a plain gather (custom_vjp in ops.py), so gradients match the
-scatter oracle, and the max is stop_gradient'd (exact by shift-invariance).
+scatter oracle, and so do the edge softmax's (its backward recomputes
+the weights from the saved row max).
 
 On this container the k workers are simulated with `jax.vmap(axis_name=...)`
 over stacked per-worker batches — identical collective semantics to the
@@ -105,13 +106,12 @@ def step_donate_argnums(lossless: bool) -> tuple:
 # `lay` = dict(esrc, edst, emask, deg, agg_order, agg_ldst, agg_btile); n_dst
 # is static
 # (from the pad plan). Aggregation targets are sized n_dst+1; index n_dst is
-# the padding sink. Every edge aggregation — the sums AND GAT's softmax
-# stabilisation max — goes through `ops.aggregate` (`backend` in {scatter,
-# tiled, pallas}); the tiled layout is per-layer, per-batch, shaped by the
-# static pad plan (LayerPad.tiled_plan), so the device step still compiles
-# once, and the GAT layer stack runs scatter-free under the tiled/pallas
-# backends (the stabilisation max is stop_gradient'd — exact, softmax is
-# shift-invariant).
+# the padding sink. Every edge aggregation goes through `ops.aggregate`
+# (the sums) or `ops.edge_softmax` (GAT's attention, one edge pass), with
+# `backend` in {scatter, tiled, pallas}; the tiled layout is per-layer,
+# per-batch, shaped by the static pad plan (LayerPad.tiled_plan), so the
+# device step still compiles once, and the GAT layer stack runs
+# scatter-free under the tiled/pallas backends.
 # ---------------------------------------------------------------------------
 
 
@@ -149,30 +149,21 @@ def _mb_gcn_layer(p, h_src, lay, n_dst: int, *, final: bool,
 def _mb_gat_layer(p, h_src, lay, n_dst: int, *, final: bool,
                   backend: str = "scatter"):
     heads, dh = p["a_src"].shape
-    z = (h_src @ p["w"]).reshape(h_src.shape[0], heads, dh)
-    s_src = jnp.einsum("nhd,hd->nh", z, p["a_src"])
-    s_dst = jnp.einsum("nhd,hd->nh", z[:n_dst], p["a_dst"])
-    s_dst_pad = jnp.pad(s_dst, ((0, 1), (0, 0)))
-    e = jax.nn.leaky_relu(s_src[lay["esrc"]] + s_dst_pad[lay["edst"]], 0.2)
-    e = jnp.where(lay["emask"][:, None], e, -1e30)
-    e_self = jax.nn.leaky_relu(s_src[:n_dst] + s_dst, 0.2)
-
-    # softmax stabilisation max through the same tiled segment-reduce as the
-    # sums; stop_gradient is exact (softmax is shift-invariant) and keeps
-    # the backward scatter-free (see ops.aggregate)
-    m = _mb_aggregate(e, lay, n_dst, backend, reduce="max")
-    m = jax.lax.stop_gradient(jnp.maximum(m[:-1], e_self))
-    m_pad = jnp.pad(m, ((0, 1), (0, 0)))
-    w = jnp.exp(e - m_pad[lay["edst"]]) * lay["emask"][:, None]
-    w_self = jnp.exp(e_self - m)
-    den = _mb_aggregate(w, lay, n_dst, backend)
-    den = den[:-1] + w_self
-    num = _mb_aggregate(
-        (w[:, :, None] * z[lay["esrc"]]).reshape(-1, heads * dh),
-        lay, n_dst, backend,
-    ).reshape(n_dst + 1, heads, dh)
-    num = num[:-1] + w_self[:, :, None] * z[:n_dst]
-    out = (num / jnp.maximum(den, 1e-16)[:, :, None]).reshape(n_dst, heads * dh)
+    z = h_src @ p["w"]                                 # [n_src, H*dh]
+    s_src = ops.attention_scores(z[:n_dst], p["a_src"])
+    s_dst = ops.attention_scores(z[:n_dst], p["a_dst"])
+    # one edge pass into the n_dst+1 rows (n_dst the padding sink), then
+    # the self edge merged in (see models.gat_layer)
+    m, den, num = ops.edge_softmax(
+        z, p["a_src"], jnp.pad(s_dst, ((0, 1), (0, 0))), lay["esrc"],
+        lay["edst"], n_dst + 1, mask=lay["emask"],
+        edge_order=lay.get("agg_order"), local_dst=lay.get("agg_ldst"),
+        block_tile=lay.get("agg_btile"), backend=backend)
+    e_self = jax.nn.leaky_relu(s_src + s_dst, 0.2)
+    _, den, num = ops.softmax_merge((m[:-1], den[:-1], num[:-1]),
+                                    ops.softmax_edge(e_self, z[:n_dst]))
+    out = (num.reshape(n_dst, heads, dh) / den[:, :, None]).reshape(
+        n_dst, heads * dh)
     out = (out + p["b"]) @ p["w_out"]
     return out if final else jax.nn.elu(out)
 

@@ -29,17 +29,18 @@ DistGNN and the paper's DistDGL setup use).
 
 Models follow the paper's setup (§4.1/§5.1): GraphSAGE (mean), GCN, GAT.
 
-Aggregation backend (`GNNSpec.agg_backend`): every edge aggregation — the
-sum-aggregations AND GAT's per-destination softmax-stabilisation max — goes
-through `kernels.ops.aggregate`, which dispatches on the knob —
+Aggregation backend (`GNNSpec.agg_backend`): every edge aggregation goes
+through `kernels.ops` — the sums through `aggregate`, GAT's attention
+through `edge_softmax` (one edge pass: row max, exp-sum and weighted z
+together) — which dispatch on the knob —
   scatter — data-dependent `at[].add` / `at[].max` (the oracle)
   tiled   — pre-sorted/pre-blocked layout (`Block.agg_order`/`agg_ldst`,
             built by the partition book) through the tiled segment-reduce:
-            jnp oracle off-TPU, the Pallas one-hot kernel on TPU. Backward
-            of the sum is a plain gather (custom_vjp), so gradients match
-            the scatter oracle to allclose; the stabilisation max is
-            stop_gradient'd (exact — softmax is shift-invariant), so the
-            O(E) edge-aggregation hot path of every model, GAT included,
+            jnp oracle off-TPU, the Pallas one-hot kernel on TPU (its sum
+            or softmax combiner). Backward of the sum is a plain gather,
+            that of the softmax a recompute from the saved row max
+            (custom_vjp), so gradients match the scatter oracle to
+            allclose, and the forward's O(E) edge hot path of every model
             is scatter-free under tiled/pallas. (The k-way replica sync
             still scatters into its bucket-sized halo buffers —
             O(replicas), the network path, not the edge hot path.)
@@ -54,6 +55,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels import ops
 
 Params = Any
 
@@ -80,11 +83,12 @@ class GNNSpec:
 
           halo/dense/local complete partial AGGREGATES (message rows):
             sage/gcn  [d_in]                (msg = masked src features)
-            gat       [H, H, H·dh]          (max scores, exp-sum, weighted z)
+            gat       [H, H, H·dh]          (the edge softmax's row max,
+                                             exp-sum and weighted z)
           ring rotates the PAYLOAD itself:
             sage/gcn  [d_in]                (payload == message width)
-            gat       [H, H+H·dh, H+H·dh]   (s_src, then the shared
-                                             [s_src | z] for den and num)
+            gat       [H·dh]                (z; each stage's softmax
+                                             partial merges locally)
 
         The byte accountants (`FullBatchTrainer.*_bytes_per_epoch`,
         `LayerwiseInference.sync_bytes`) and the runtime reconciliation
@@ -97,7 +101,7 @@ class GNNSpec:
                 h = self.gat_heads
                 dh = max(dout // h, 1)
                 if mode == "ring":
-                    out.append([h, h + h * dh, h + h * dh])
+                    out.append([h * dh])
                 else:
                     out.append([h, h, h * dh])
             else:
@@ -173,59 +177,23 @@ def gat_layer(p, x, blk, sync, *, final: bool,
               backend: str = "scatter") -> jnp.ndarray:
     n = x.shape[0]
     h_heads, dh = p["a_src"].shape
-    z = (x @ p["w"]).reshape(n, h_heads, dh)
-    s_src = jnp.einsum("nhd,hd->nh", z, p["a_src"])  # [n, H]
-    s_dst = jnp.einsum("nhd,hd->nh", z, p["a_dst"])
-
-    neg_inf = jnp.asarray(-1e30, x.dtype)
-
-    def score(src_s, dst):
-        # attention logit of an edge: src payload rows + the LOCAL dst table
-        return jax.nn.leaky_relu(src_s + s_dst[dst], 0.2)
-
-    # 1) global max per destination (for a stable softmax). Rows no valid
-    # edge reaches come back at the -1e30 mask floor (scatter) or -inf
-    # (tiled/pallas drop masked edges) — the e_self/-1e29 clamps below make
-    # the backends agree exactly. Softmax is shift-invariant, so the shift
-    # needs no gradient: stop_gradient is exact and keeps the backward free
-    # of any scatter-max / argmax transpose (see ops.aggregate).
-    m = sync.edge_aggregate(
-        blk, s_src,
-        lambda src, dst, mask: jnp.where(mask[:, None], score(src, dst),
-                                         neg_inf),
-        reduce="max", backend=backend)
+    z = x @ p["w"]                                   # [n, H*dh]
+    s_src = ops.attention_scores(z, p["a_src"])      # [n, H]
+    s_dst = ops.attention_scores(z, p["a_dst"])
+    # one edge pass: the complete (max, exp-sum, weighted z) over the
+    # neighbours, which the sync rescales to the global max before summing
+    # partials (ring: merges stage by stage). The payload is z alone; each
+    # edge's source score is a_src . z_src, so a lossy wire codec hands the
+    # weight and the message the same decoded row.
+    agg = sync.edge_aggregate(blk, z, (p["a_src"], s_dst), reduce="softmax",
+                              backend=backend)
+    # the self edge, merged after completion, ungated: completed aggregates
+    # are replica-consistent
     e_self = jax.nn.leaky_relu(s_src + s_dst, 0.2)
-    m = jnp.maximum(m, e_self)
-    m_safe = jax.lax.stop_gradient(jnp.maximum(m, -1e29))  # isolated vertices
-
-    # 2) + 3) share ONE payload carrying [s_src | z]: a single rotation/
-    # gather serves both the weight and the message, and — crucially for
-    # lossy wire codecs — the denominator and numerator decode the SAME
-    # encoded scores. Codec encoding is deterministic, so both aggregates
-    # see bit-identical attention weights and the softmax normalisation
-    # survives quantisation (separate payloads would quantise s_src at two
-    # different per-tensor scales and bias num/den against each other).
-    payload = jnp.concatenate([s_src, z.reshape(n, h_heads * dh)], axis=1)
-
-    # 2) global sum of exp (self term added post-completion, ungated:
-    # completed aggregates are replica-consistent)
-    den = sync.edge_aggregate(
-        blk, payload,
-        lambda src, dst, mask: (jnp.exp(score(src[:, :h_heads], dst)
-                                        - m_safe[dst]) * mask[:, None]),
-        backend=backend)
-    w_self = jnp.exp(e_self - m_safe)
-    den = jnp.maximum(den + w_self, 1e-16)
-
-    def weighted_msg(src, dst, mask):
-        w = jnp.exp(score(src[:, :h_heads], dst) - m_safe[dst]) * mask[:, None]
-        zf = src[:, h_heads:].reshape(-1, h_heads, dh)
-        return (w[:, :, None] * zf).reshape(-1, h_heads * dh)
-
-    num = sync.edge_aggregate(blk, payload, weighted_msg, backend=backend)
-    num = num.reshape(n, h_heads, dh) + w_self[:, :, None] * z
-
-    out = (num / den[:, :, None]).reshape(n, h_heads * dh) + p["b"]
+    _, den, num = ops.softmax_merge(agg, ops.softmax_edge(e_self, z))
+    # den >= 1: the row's largest score contributes exp(0)
+    out = (num.reshape(n, h_heads, dh) / den[:, :, None]).reshape(
+        n, h_heads * dh) + p["b"]
     out = out @ p["w_out"]
     return out if final else jax.nn.elu(out)
 

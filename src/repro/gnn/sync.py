@@ -7,8 +7,13 @@ The `SyncStrategy` protocol is ONE method every model layer builds on:
 Give it per-vertex payload rows and a message function
 `msg_fn(src_rows, dst_idx, edge_mask) -> [E, d]`; it returns the COMPLETE
 (globally consistent) per-destination aggregate over the symmetrised
-adjacency. `psum(v)` completes scalars (the loss). How completion happens —
-and what it costs — is the strategy:
+adjacency. `reduce="softmax"` is GAT's edge softmax (`ops.edge_softmax`):
+the payload is z, `msg_fn` is the pair (a_src, s_dst) of source attention
+vectors and local destination scores, and the result is the complete
+(max, denominator, numerator) triple, the partials merged by
+`ops.softmax_merge` or rescaled to the completed max before their sums.
+`psum(v)` completes scalars (the loss). How completion happens — and what
+it costs — is the strategy:
 
   LocalSync  — k=1 oracle: one local `ops.aggregate` pass, nothing moves.
   DenseSync  — aggregate locally, scatter into a global [V+1, d] buffer and
@@ -206,6 +211,8 @@ class _PartialAggSync(_CodecSync):
 
     def edge_aggregate(self, blk: "Block", payload, msg_fn, *,
                        reduce: str = "sum", backend: str = "scatter"):
+        if reduce == "softmax":
+            return self._edge_softmax(blk, payload, msg_fn, backend)
         object.__setattr__(self, "_cur_layer", self._take_layer())
         n = payload.shape[0]
         with jax.named_scope("edge.gather"):
@@ -222,6 +229,26 @@ class _PartialAggSync(_CodecSync):
         )
         agg = self.reduce_max(agg) if reduce == "max" else self.reduce_sum(agg)
         return self.broadcast(agg)
+
+    def _complete(self, h, reduce_fn):
+        object.__setattr__(self, "_cur_layer", self._take_layer())
+        return self.broadcast(reduce_fn(h))
+
+    def _edge_softmax(self, blk: "Block", z, scores, backend: str):
+        """The local edge softmax partial over the symmetrised edge list,
+        completed as three aggregates: the row max, then the denominator
+        and the numerator, each rescaled to the completed max first."""
+        a_src, s_dst = scores
+        m, den, num = ops.edge_softmax(
+            z, a_src, s_dst, jnp.concatenate([blk.esrc, blk.edst]),
+            jnp.concatenate([blk.edst, blk.esrc]), z.shape[0],
+            mask=jnp.concatenate([blk.emask, blk.emask]),
+            edge_order=blk.agg_order, local_dst=blk.agg_ldst,
+            block_tile=blk.agg_btile, backend=backend)
+        m_all = self._complete(m, self.reduce_max)
+        m_all, den, num = ops.softmax_rescale((m, den, num), m_all)
+        return (m_all, self._complete(den, self.reduce_sum),
+                self._complete(num, self.reduce_sum))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -470,17 +497,25 @@ class RingSync(_CodecSync):
             else:
                 nxt = nxt_meta = None
             cur = codec.decode(buf, meta)
-            messages = msg_fn(cur[blk.chunk_esrc[s]], blk.chunk_edst[s],
-                              blk.chunk_emask[s])
-            part = ops.aggregate(
-                messages, blk.chunk_edst[s], n,
+            layout = dict(
                 edge_order=blk.chunk_agg_order[s] if tiled else None,
                 local_dst=blk.chunk_agg_ldst[s] if tiled else None,
                 block_tile=blk.chunk_agg_btile[s] if tiled else None,
-                backend=backend, reduce=reduce,
-            )
+                backend=backend)
+            if reduce == "softmax":
+                a_src, s_dst = msg_fn
+                part = ops.edge_softmax(
+                    cur, a_src, s_dst, blk.chunk_esrc[s], blk.chunk_edst[s],
+                    n, mask=blk.chunk_emask[s], **layout)
+            else:
+                messages = msg_fn(cur[blk.chunk_esrc[s]], blk.chunk_edst[s],
+                                  blk.chunk_emask[s])
+                part = ops.aggregate(messages, blk.chunk_edst[s], n,
+                                     reduce=reduce, **layout)
             if acc is None:
                 acc = part
+            elif reduce == "softmax":
+                acc = ops.softmax_merge(acc, part)
             else:
                 acc = jnp.maximum(acc, part) if reduce == "max" else acc + part
             if nxt is not None:

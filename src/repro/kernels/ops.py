@@ -15,8 +15,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
+from repro.obs.trace import get_tracer
 from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
+from repro.kernels.segment_spmm import SOFTMAX_FLOOR
+from repro.kernels.segment_spmm import segment_softmax as _softmax_pallas
 from repro.kernels.segment_spmm import segment_spmm as _spmm_pallas
 from repro.kernels.tiling import (  # noqa: F401 (re-exported host-layout API)
     DEFAULT_BLOCK_E,
@@ -185,9 +188,8 @@ def _tiled_aggregate_max(num_rows, tile_v, block_e, use_pallas, interpret,
     max and gets zero cotangent even if its message ties the surviving row
     max (the scatter oracle, which still sees that edge, would hand it a
     tie share — gradient parity on dropped edges needs them strictly below
-    the surviving max). The GAT hot path wraps this in lax.stop_gradient
-    and never runs the backward — the vjp exists for standalone use of
-    aggregate(reduce="max").
+    the surviving max). No model calls it: GAT's softmax takes its row
+    max inside `edge_softmax`.
     """
     del dst  # forward uses the tiled layout only; dst feeds the backward
     e, f = messages.shape
@@ -277,20 +279,14 @@ def aggregate(
             clamp with jnp.maximum against a finite floor before exp/log).
             Backward is a masked-argmax gather, split evenly among tied
             edges (the scatter oracle's convention, so gradients match it
-            even on ties). GNN softmax stabilisation — the one max
-            on the GAT hot path — does NOT need it: softmax is
-            shift-invariant, so the stabilisation max is wrapped in
-            lax.stop_gradient at the call sites (gnn/models.py,
-            gnn/minibatch.py), which is exact and keeps the backward free of
-            any scatter/argmax transpose.
+            even on ties). GAT's softmax does not use it: `edge_softmax`
+            takes the row max in its own single pass.
 
     The tiled layout may drop `valid`-masked edges — forward values still
     match the scatter oracle as long as dropped messages carry the reduce
     identity's certainty: identically zero for sum, at or below every
-    surviving score for max (the GAT layers mask scores to -1e30 > -inf,
-    and clamp the aggregate before use, so both backends agree after
-    clamping). Gradients match too, except that a dropped edge exactly
-    TYING the surviving row max gets zero cotangent here (it is not part of
+    surviving score for max. Gradients match too, except that a dropped
+    edge exactly TYING the surviving row max gets zero cotangent here (it is not part of
     the computed max) while the scatter oracle — which still sees it —
     hands it a tie share; strict inequality on dropped edges restores full
     parity.
@@ -324,6 +320,252 @@ def aggregate(
         num_rows, tile_v, block_e, use_pallas, interpret,
         messages, dst, edge_order, local_dst, block_tile,
     )
+
+
+# ---------------------------------------------------------------------------
+# edge_softmax — GAT's attention-weighted aggregate, one edge pass
+# ---------------------------------------------------------------------------
+
+# edges per step of the softmax backward's loop: bounds its per-edge
+# transients ([chunk, W] f32) whatever the graph's size
+SOFTMAX_BWD_CHUNK = 1 << 17
+
+
+def attention_scores(z: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
+    """Per-head scores a_h . z_h of rows `z` [n, H*dh] for `a` [H, dh]:
+    [n, H], in full float32 (the kernel scores its source rows so too)."""
+    heads, dh = a.shape
+    return jnp.einsum("nhd,hd->nh", z.reshape(-1, heads, dh), a,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _per_lane(t: jnp.ndarray, dh: int) -> jnp.ndarray:
+    """[n, H] per-head values repeated over each head's dh lanes."""
+    n, heads = t.shape
+    return jnp.broadcast_to(t[:, :, None], (n, heads, dh)).reshape(
+        n, heads * dh)
+
+
+def softmax_rescale(part: tuple, m_new: jnp.ndarray) -> tuple:
+    """An (m, den, num) partial of an edge softmax shifted to the row max
+    `m_new` (at least its own m). Neither max carries a gradient: the
+    rescale is exact, and softmax does not depend on the shift."""
+    m, den, num = part
+    m_new = jax.lax.stop_gradient(m_new)
+    scale = jnp.exp(jax.lax.stop_gradient(m) - m_new)
+    dh = num.shape[-1] // m.shape[-1]
+    return m_new, den * scale, num * _per_lane(scale, dh)
+
+
+def softmax_edge(score: jnp.ndarray, z: jnp.ndarray) -> tuple:
+    """The (m, den, num) partial of one edge a row: its score [n, H] and
+    source rows z [n, H*dh] (GAT's self edge). Its weight is
+    exp(score - score) = 1, which still carries the score's gradient."""
+    m = jax.lax.stop_gradient(score)
+    w = jnp.exp(score - m)
+    return m, w, z * _per_lane(w, z.shape[-1] // score.shape[-1])
+
+
+def softmax_merge(a: tuple, b: tuple) -> tuple:
+    """Merge two (m, den, num) partials of one edge softmax over disjoint
+    edge sets (a self edge enters as `softmax_edge`)."""
+    m = jnp.maximum(jax.lax.stop_gradient(a[0]), jax.lax.stop_gradient(b[0]))
+    _, d1, n1 = softmax_rescale(a, m)
+    _, d2, n2 = softmax_rescale(b, m)
+    return m, d1 + d2, n1 + n2
+
+
+def _softmax_scatter(z, a_src, s_dst, src, dst, mask, num_rows):
+    """The scatter oracle: a segment-max, then two segment-sums."""
+    heads, dh = a_src.shape
+    dstc = jnp.minimum(dst, num_rows)
+    pre = (attention_scores(z, a_src)[src]
+           + jnp.pad(s_dst, ((0, 1), (0, 0)))[dstc])
+    s = jnp.where(mask[:, None], jax.nn.leaky_relu(pre, 0.2), -jnp.inf)
+    m = jnp.full((num_rows + 1, heads), SOFTMAX_FLOOR, s.dtype)
+    m = jax.lax.stop_gradient(m.at[dstc].max(s))
+    w = jnp.exp(s - m[dstc])
+    den = jnp.zeros((num_rows + 1, heads), w.dtype).at[dstc].add(w)
+    msg = (w[:, :, None] * z[src].reshape(-1, heads, dh)).reshape(
+        -1, heads * dh)
+    num = jnp.zeros((num_rows + 1, heads * dh), msg.dtype).at[dstc].add(msg)
+    return m[:num_rows], den[:num_rows], num[:num_rows]
+
+
+def _softmax_tile_f(w: int, dh: int) -> int:
+    """A feature tile of whole heads: 128 lanes where they hold whole
+    heads, a head where it spans 128-lane multiples, else every lane."""
+    if w % DEFAULT_TILE_F == 0 and DEFAULT_TILE_F % dh == 0:
+        return DEFAULT_TILE_F
+    if dh % DEFAULT_TILE_F == 0:
+        return dh
+    return w
+
+
+def _softmax_kernel(num_rows, tile_v, block_e, interpret, z, a_src, s_dst,
+                    layout_src, local_dst, block_tile):
+    """(m, den, num) of the kernel's softmax combiner: [num_rows, H] for m
+    and den, [num_rows, H*dh] for num."""
+    heads, dh = a_src.shape
+    w = heads * dh
+    rows_padded, _ = tiled_shape(num_rows, tile_v)
+    tile_f = _softmax_tile_f(w, dh)
+    with jax.named_scope("edge.reorder"):
+        # one gather from the rows straight into layout order
+        ordered = z[layout_src]
+    with jax.named_scope("edge.softmax"):
+        per_tile = tile_f // dh
+        rows = -(-per_tile // 8) * 8
+        # a_rows[ft, h] = a_src of the tile's h-th head, in its lanes
+        a_lanes = (jnp.eye(rows, per_tile, dtype=a_src.dtype)[:, :, None]
+                   * a_src.reshape(w // tile_f, 1, per_tile, dh))
+        a_rows = a_lanes.reshape(w // tile_f, rows, tile_f)
+        s_lanes = jnp.pad(_per_lane(s_dst, dh),
+                          ((0, rows_padded - num_rows), (0, 0)))
+        num, den, m = _softmax_pallas(
+            ordered, local_dst, block_tile, a_rows, s_lanes, rows_padded,
+            head_dim=dh, block_e=block_e, tile_v=tile_v, tile_f=tile_f,
+            interpret=interpret or not _on_tpu())
+        head = lambda t: t[:num_rows, ::dh]  # noqa: E731
+        return head(m), head(den), num[:num_rows]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _tiled_softmax(num_rows, tile_v, block_e, interpret, z, a_src, s_dst,
+                   src, dst, mask, layout_src, local_dst, block_tile):
+    """The edge softmax through the tiled layout and the kernel's softmax
+    combiner. The layout must drop exactly the masked edges."""
+    del src, dst, mask  # the layout holds them; they feed the backward
+    return _softmax_kernel(num_rows, tile_v, block_e, interpret, z, a_src,
+                           s_dst, layout_src, local_dst, block_tile)
+
+
+def _tiled_softmax_fwd(num_rows, tile_v, block_e, interpret, z, a_src, s_dst,
+                       src, dst, mask, layout_src, local_dst, block_tile):
+    m, den, num = _softmax_kernel(num_rows, tile_v, block_e, interpret, z,
+                                  a_src, s_dst, layout_src, local_dst,
+                                  block_tile)
+    # node-sized residuals and the block's own index arrays only
+    return (m, den, num), (z, a_src, s_dst, src, dst, mask, m)
+
+
+@jax.named_scope("edge.softmax")
+def _tiled_softmax_bwd(num_rows, tile_v, block_e, interpret, res, g):
+    """Recompute each edge's weight from the saved row max (the forward
+    keeps nothing per edge), chunk by chunk over the edges, each chunk
+    one gather of its source rows and one of its destination rows, a
+    scatter-add into the source rows and a narrow one ([chunk, H]) into
+    the destination rows:
+
+      w      = exp(leaky_relu(pre) - m[dst])
+      ds     = w (g_num[dst] . z_src + g_den[dst]) leaky_relu'(pre)
+      dz    += w g_num[dst] + ds a_src              (to the source row)
+      ds_dst += ds                                  (to the destination row)
+      da_src += ds z_src
+    """
+    z, a_src, s_dst, src, dst, mask, m = map(jnp.asarray, res)
+    _, g_den, g_num = g
+    heads, dh = a_src.shape
+    w_all = heads * dh
+    e = src.shape[0]
+    chunk = min(SOFTMAX_BWD_CHUNK, max(e, 1))
+    pad = -e % chunk
+    src_c = jnp.pad(src, (0, pad)).reshape(-1, chunk)
+    dst_c = jnp.pad(dst, (0, pad), constant_values=num_rows).reshape(
+        -1, chunk)
+    mask_c = jnp.pad(mask, (0, pad)).reshape(-1, chunk)
+    # one row a source: [z | s_src]; one a destination (and a zero pad
+    # row): [g_num | s_dst | m | g_den]
+    src_rows = jnp.concatenate([z, attention_scores(z, a_src)], axis=1)
+    dst_rows = jnp.pad(jnp.concatenate([g_num, s_dst, m, g_den], axis=1),
+                       ((0, 1), (0, 0)))
+
+    def body(carry, xs):
+        dz, dsd, da = carry
+        s_i, d_i, k_i = xs
+        d_i = jnp.minimum(d_i, num_rows)
+        sr, dr = src_rows[s_i], dst_rows[d_i]
+        zs = sr[:, :w_all].reshape(-1, heads, dh)
+        gn = dr[:, :w_all].reshape(-1, heads, dh)
+        sd, md, gd = (dr[:, w_all + i * heads:w_all + (i + 1) * heads]
+                      for i in range(3))
+        pre = sr[:, w_all:] + sd
+        w = jnp.exp(jnp.where(k_i[:, None],
+                              jax.nn.leaky_relu(pre, 0.2) - md, -jnp.inf))
+        ds = (w * (jnp.einsum("ehd,ehd->eh", gn, zs) + gd)
+              * jnp.where(pre >= 0, 1.0, 0.2))
+        dzs = w[:, :, None] * gn + ds[:, :, None] * a_src
+        dz = dz.at[s_i].add(dzs.reshape(-1, w_all))
+        dsd = dsd.at[d_i].add(ds)  # the pad row num_rows takes the pad's 0s
+        return (dz, dsd, da + jnp.einsum("eh,ehd->hd", ds, zs)), None
+
+    (dz, dsd, da), _ = jax.lax.scan(
+        body, (jnp.zeros_like(z), jnp.zeros((num_rows + 1, heads), z.dtype),
+               jnp.zeros_like(a_src)),
+        (src_c, dst_c, mask_c))
+    return dz, da, dsd[:num_rows], None, None, None, None, None, None
+
+
+_tiled_softmax.defvjp(_tiled_softmax_fwd, _tiled_softmax_bwd)
+
+
+def edge_softmax(
+    z: jnp.ndarray,        # [n, H*dh] source rows (heads concatenated)
+    a_src: jnp.ndarray,    # [H, dh] source attention vectors
+    s_dst: jnp.ndarray,    # [num_rows, H] destination scores
+    src: jnp.ndarray,      # [E] int32 source row of each edge (< n)
+    dst: jnp.ndarray,      # [E] int32 destination row (< num_rows)
+    num_rows: int,
+    *,
+    mask: jnp.ndarray,     # [E] bool; False edges take no part
+    edge_order: jnp.ndarray | None = None,  # from prepare_tiled_edges
+    local_dst: jnp.ndarray | None = None,
+    block_tile: jnp.ndarray | None = None,
+    backend: str = "scatter",
+    tile_v: int = DEFAULT_TILE_V,
+    block_e: int = DEFAULT_BLOCK_E,
+    interpret: bool = False,
+) -> tuple:
+    """GAT's edge softmax over the edges into each row, per head:
+
+      s_e = leaky_relu(a_src . z[src_e] + s_dst[dst_e], 0.2)
+      m   = max s_e            den = sum exp(s_e - m)
+      num = sum exp(s_e - m) z[src_e]
+
+    Returns (m [num_rows, H], den [num_rows, H], num [num_rows, H*dh]), a
+    partial that `softmax_merge` combines with others (a self edge, other
+    partitions' edges). Rows no edge reaches give (SOFTMAX_FLOOR, 0, 0).
+    `m` carries no gradient (the merge and the quotient num / den do not
+    depend on the shift).
+
+    backend: scatter — a segment-max and two segment-sums (the oracle);
+    tiled — one pass of the kernel's softmax combiner over the
+    `prepare_tiled_edges` layout (built with valid=mask) on a TPU, the
+    oracle elsewhere, its source rows gathered straight into layout
+    order (`src` composed with `edge_order`); pallas — the kernel everywhere (interpreted off a
+    TPU). The kernel path's backward recomputes the edge weights from the
+    saved row max and keeps nothing per edge between the passes. Scopes:
+    `edge.reorder` (the rows' gather into layout order) and `edge.softmax`
+    (the kernel, its backward, the oracle); counter `edge.softmax.calls`.
+    """
+    if backend not in AGG_BACKENDS:
+        raise ValueError(f"unknown aggregate backend {backend!r}; "
+                         f"options: {AGG_BACKENDS}")
+    # one per traced aggregate: counted when jax traces, not per step
+    get_tracer().add("edge.softmax.calls", 1)
+    if backend == "scatter" or (backend == "tiled" and not _on_tpu()):
+        with jax.named_scope("edge.softmax"):
+            return _softmax_scatter(z, a_src, s_dst, src, dst, mask,
+                                    num_rows)
+    assert (edge_order is not None and local_dst is not None
+            and block_tile is not None), (
+        "tiled/pallas backends need the prepare_tiled_edges layout")
+    layout_src = jnp.concatenate([src, jnp.zeros((1,), src.dtype)])[
+        edge_order]
+    m, den, num = _tiled_softmax(num_rows, tile_v, block_e, interpret, z,
+                                 a_src, s_dst, src, dst, mask, layout_src,
+                                 local_dst, block_tile)
+    return jax.lax.stop_gradient(m), den, num
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Pallas TPU kernel: tiled segment-reduce (sum | max) for GNN aggregation.
+"""Pallas TPU kernel: tiled segment-reduce (sum | max | softmax) for GNN
+aggregation.
 
 The paper's compute hot spot is sparse neighbor aggregation (SpMM over the
 partition-local edge list). TPU adaptation of the insight (DESIGN.md §2):
@@ -30,6 +31,22 @@ TILE_V x BLOCK_E one-hot (+ TILE_V x CHUNK_E x TILE_F and a BLOCK_E x
 TILE_F int32 dst column for the max sweep) — all tiled to multiples of
 (8, 128) lanes.
 
+The softmax combiner (`segment_softmax`, GAT's edge softmax) runs on the
+same grid and layout. Its messages are the source rows z of the heads in a
+feature tile; per edge block it scores every (row, edge) pair of the tile
+in VMEM, `leaky_relu(a_src . z_src + s_dst[row], 0.2)` masked by the
+one-hot, and folds the block into three resident accumulators, an online
+softmax per row and head as in flash attention:
+
+  m_new = max(m, masked row max of the block)
+  P     = exp(masked scores - m_new)           [tile_v, block_e]
+  num   = num * exp(m - m_new) + P @ z          (MXU)
+  den   = den * exp(m - m_new) + row sums of P
+
+The source scores come from z by one small matmul with the heads' `a_src`
+rows, so nothing per edge but z is read. Each head's max and denominator
+are kept repeated over its lanes, so the outputs share one block.
+
 The jit'd wrapper (ops.py) validates shapes and falls back to the pure-jnp
 oracle (ref.py) on non-TPU backends; interpret=True is used by the tests.
 """
@@ -50,6 +67,10 @@ from repro.kernels.tiling import (  # noqa: F401 (canonical tile constants)
 )
 
 COMBINERS = ("sum", "max")
+
+# the softmax combiner's running-max floor: a row no edge reaches keeps it
+# (den 0, num 0); finite, so that rescaling by exp(m - m_new) never makes NaN
+SOFTMAX_FLOOR = -1e30
 
 # VMEM budget for the max sweep's [tile_v, chunk_e, tile_f] intermediate
 _MAX_SWEEP_BYTES = 2 << 20
@@ -198,3 +219,123 @@ def segment_spmm(
         interpret=interpret,
         name=f"segment_spmm_{combiner}",
     )(block_tile.astype(jnp.int32), dst_blocks, messages)
+
+
+def _segment_softmax_kernel(tile_ref, dst_ref, z_ref, a_ref, sdst_ref,
+                            num_ref, den_ref, m_ref, *, block_e, tile_v,
+                            head_dim):
+    """One grid step of the softmax combiner: fold one edge block into its
+    row tile's accumulators.
+
+    z_ref:    [block_e, tile_f] source rows of the block's edges
+    a_ref:    [rows, tile_f]    row h holds head h's `a_src` in its lanes
+    sdst_ref: [tile_v, tile_f]  destination scores, repeated over each
+                                head's lanes
+    num_ref, den_ref, m_ref: [tile_v, tile_f] resident accumulators; den
+                                and m repeated over each head's lanes
+    """
+    b = pl.program_id(1)
+
+    @pl.when((b == 0) | (tile_ref[b] != tile_ref[jnp.maximum(b - 1, 0)]))
+    def _init():
+        num_ref[...] = jnp.zeros_like(num_ref)
+        den_ref[...] = jnp.zeros_like(den_ref)
+        m_ref[...] = jnp.full_like(m_ref, SOFTMAX_FLOOR)
+
+    tile_f = z_ref.shape[1]
+    heads = tile_f // head_dim
+    z = z_ref[...].astype(jnp.float32)
+    hits = (jax.lax.broadcasted_iota(jnp.int32, (tile_v, block_e), 0)
+            == dst_ref[...])
+    # source scores of the block, one row per head: [rows, block_e]
+    s_src = jax.lax.dot_general(
+        a_ref[...], z, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    m_old, s_dst = m_ref[...], sdst_ref[...]
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (tile_v, tile_f),
+                                         1) // head_dim
+    z_head = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) // head_dim
+    # per head: [tile_v, 1] columns (rescale, new max, weight sums), and
+    # the block's [tile_v, tile_f] products, all heads' lanes together
+    cols = {"scale": None, "m": None, "den": None}
+    part = jnp.zeros(num_ref.shape, jnp.float32)
+    for h in range(heads):
+        if heads == 1:
+            col = lambda t: t[:, :1]  # noqa: E731 (every lane holds it)
+            zh = z
+        else:
+            col = lambda t: jnp.max(  # noqa: E731 (head h's lanes)
+                jnp.where(lane_head == h, t, SOFTMAX_FLOOR), axis=1,
+                keepdims=True)
+            zh = jnp.where(z_head == h, z, 0.0)
+        pre = s_src[h:h + 1, :] + col(s_dst)
+        s = jnp.where(hits, jnp.maximum(pre, 0.2 * pre), -jnp.inf)
+        m_h = col(m_old)
+        mn_h = jnp.maximum(m_h, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - mn_h)                    # 0 where no edge hits
+        part = part + jax.lax.dot(p, zh, preferred_element_type=jnp.float32)
+        got = {"scale": jnp.exp(m_h - mn_h), "m": mn_h,
+               "den": jnp.sum(p, axis=1, keepdims=True)}
+        for k, v in got.items():
+            cols[k] = v if heads == 1 else jnp.where(
+                lane_head == h, v, 0.0 if cols[k] is None else cols[k])
+    scale = cols["scale"]
+    num_ref[...] = num_ref[...] * scale + part
+    den_ref[...] = den_ref[...] * scale + cols["den"]
+    m_ref[...] = jnp.broadcast_to(cols["m"], m_ref.shape)
+
+
+def segment_softmax(
+    z: jnp.ndarray,           # [E, W] source rows, pre-sorted by dst tile
+    local_dst: jnp.ndarray,   # [E] int32 row id WITHIN the edge's row tile
+    block_tile: jnp.ndarray,  # [E / block_e] int32 row tile of each block
+    a_rows: jnp.ndarray,      # [W / tile_f, rows, tile_f] heads' a_src
+    s_dst: jnp.ndarray,       # [num_rows, W] dst scores over head lanes
+    num_rows: int,
+    *,
+    head_dim: int,
+    block_e: int = DEFAULT_BLOCK_E,
+    tile_v: int = DEFAULT_TILE_V,
+    tile_f: int = DEFAULT_TILE_F,
+    interpret: bool = False,
+) -> tuple:
+    """The softmax combiner on the layout `segment_spmm` takes. Returns
+    (num, den, m), each [num_rows, W]; den and m are repeated over each
+    head's `head_dim` lanes. A feature tile holds whole heads (`tile_f` is
+    a multiple of `head_dim`), and `a_rows[ft]` holds in row h the `a_src`
+    of the tile's h-th head, in that head's lanes. Rows no edge reaches
+    come back as (0, 0, SOFTMAX_FLOOR)."""
+    e, w = z.shape
+    assert num_rows % tile_v == 0, (num_rows, tile_v)
+    assert w % tile_f == 0 and tile_f % head_dim == 0, (w, tile_f, head_dim)
+    n_blocks = block_tile.shape[0]
+    assert e == n_blocks * block_e, (e, n_blocks, block_e)
+    rows = a_rows.shape[1]
+    kernel = functools.partial(_segment_softmax_kernel, block_e=block_e,
+                               tile_v=tile_v, head_dim=head_dim)
+    dst_blocks = local_dst.reshape(n_blocks, 1, block_e)
+    row_spec = pl.BlockSpec((tile_v, tile_f),
+                            lambda ft, b, tiles: (tiles[b], ft))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(w // tile_f, n_blocks),
+        in_specs=[
+            pl.BlockSpec((None, 1, block_e), lambda ft, b, tiles: (b, 0, 0)),
+            pl.BlockSpec((block_e, tile_f), lambda ft, b, tiles: (b, ft)),
+            pl.BlockSpec((None, rows, tile_f),
+                         lambda ft, b, tiles: (ft, 0, 0)),
+            row_spec,
+        ],
+        out_specs=[row_spec] * 3,
+    )
+    out = jax.ShapeDtypeStruct((num_rows, w), jnp.float32)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[out] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="segment_spmm_softmax",
+    )(block_tile.astype(jnp.int32), dst_blocks, z, a_rows, s_dst)

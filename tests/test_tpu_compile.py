@@ -73,8 +73,8 @@ def _shapes(tree, sharding):
                                         ("sum", 4), ("max", 4)])
 def test_segment_spmm_compiles_for_v5e(one_chip, kernel_dispatch, combiner,
                                        f):
-    """The paper width (F=512, 4 feature tiles) and GAT's 4-head score
-    aggregate on a ragged layout of 3 row tiles in 6 edge blocks: the
+    """The paper width (F=512, 4 feature tiles) and a 4 wide aggregate on
+    a ragged layout of 3 row tiles in 6 edge blocks: the
     scalar-prefetched block_tile map picks each block's output block, and
     the grid revisits it across a tile's consecutive blocks."""
     n_tiles, n_blocks = 3, 6
@@ -90,18 +90,25 @@ def test_segment_spmm_compiles_for_v5e(one_chip, kernel_dispatch, combiner,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("model,aggregates", [("sage", 1), ("gat", 3)])
-def test_fullbatch_step_compiles_for_v5e(one_chip, kernel_dispatch, model,
-                                         aggregates):
-    """One full-batch train step at the paper width F = H = 512 on the
-    tiled backend: every layer's aggregates run the kernel, whose calls
-    the compiled step names by combiner, and the edge path's scopes
-    survive the TPU compiler's fusions."""
+# per model: the kernel call of each layer, the scopes the forward pass
+# keeps (`jvp(...)`) and those its backward keeps (`transpose(jvp(...))`)
+STEP_CALLS = {
+    "sage": ("segment_spmm_sum", ("edge.gather", "edge.reorder",
+                                  "edge.reduce"),
+             ("edge.gather", "edge.reduce")),
+    "gat": ("segment_spmm_softmax", ("edge.reorder", "edge.softmax"),
+            ("edge.softmax",)),
+}
+
+
+def _fullbatch_step(model, scale, one_chip):
+    """The full-batch train step at the paper width F = H = 512 on the
+    tiled backend over OR at `scale`, k = 1, compiled for one v5e."""
     from repro.core.graph import paper_graph
     from repro.gnn.fullbatch import FullBatchTrainer
     from repro.gnn.models import GNNSpec
 
-    g = paper_graph("OR", scale=0.01, seed=0)
+    g = paper_graph("OR", scale=scale, seed=0)
     rng = np.random.default_rng(0)
     feats = rng.normal(size=(g.num_vertices, 512)).astype(np.float32)
     labels = rng.integers(0, 16, g.num_vertices).astype(np.int32)
@@ -110,20 +117,57 @@ def test_fullbatch_step_compiles_for_v5e(one_chip, kernel_dispatch, model,
                    num_classes=16, num_layers=3, agg_backend="tiled")
     tr = FullBatchTrainer.build(g, np.zeros(g.num_edges, np.int32), 1, spec,
                                 feats, labels, train)
-    compiled = tr._train_step.lower(
+    return g, tr._train_step.lower(
         *_shapes((tr.params, tr.opt_state, tr.blocks), one_chip)).compile()
+
+
+def _kernel_calls(hlo: str) -> list:
+    return sorted(re.findall(
+        r"%(segment_spmm_(?:sum|max|softmax))\.\d+ = .*?custom-call\(", hlo))
+
+
+@pytest.mark.parametrize("model,aggregates", [("sage", 1), ("gat", 3)])
+def test_fullbatch_step_compiles_for_v5e(one_chip, kernel_dispatch, model,
+                                         aggregates):
+    """One full-batch train step: each layer makes one kernel call, which
+    the compiled step names by combiner (sage sums, GAT's edge softmax
+    computes the layer's three aggregates, row max, exp-sum and weighted
+    z, in one pass), and the edge path's scopes survive the TPU compiler's
+    fusions."""
+    from repro.gnn.models import GNNSpec
+
+    spec = GNNSpec(model=model, feature_dim=512, hidden_dim=512,
+                   num_classes=16, num_layers=3)
+    assert [len(d) for d in spec.aggregate_dims()] == [aggregates] * 3
+    _, compiled = _fullbatch_step(model, 0.01, one_chip)
     hlo = compiled.as_text()
-    assert hlo.count("tpu_custom_call") == aggregates * spec.num_layers
-    calls = re.findall(r"%(segment_spmm_(?:sum|max))\.\d+ = \S+ custom-call",
-                       hlo)
-    maxes = spec.num_layers if model == "gat" else 0
-    assert sorted(calls) == (["segment_spmm_max"] * maxes + [
-        "segment_spmm_sum"] * (aggregates * spec.num_layers - maxes))
+    call, forward, backward = STEP_CALLS[model]
+    assert hlo.count("tpu_custom_call") == spec.num_layers
+    assert _kernel_calls(hlo) == [call] * 3
     names = set(op_names_from_hlo(hlo).values())
-    for scope in ("edge.gather", "edge.reorder", "edge.reduce"):
+    for scope in forward:
         assert any(f"/jvp({scope})/" in n for n in names), scope
-    for scope in ("edge.gather", "edge.reduce"):
+    for scope in backward:
         assert any(f"/transpose(jvp({scope}))/" in n for n in names), scope
+
+
+def test_gat_step_at_or_1_fits_one_v5e(one_chip, kernel_dispatch):
+    """GAT's full-batch step on the sage cell's graph, OR 1.0 (25,000 V,
+    1,342,278 E): the one-pass edge softmax keeps nothing per edge between
+    the passes, so the step fits one chip's HBM (the three-pass softmax
+    needed 37 GB there), and no per-edge array narrower than a feature
+    tile, [E, H], is allocated."""
+    g, compiled = _fullbatch_step("gat", 1.0, one_chip)
+    assert g.num_edges == 1342278
+    mem = compiled.memory_analysis()
+    used = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert used < 15.75 * 2 ** 30, used
+    hlo = compiled.as_text()
+    assert _kernel_calls(hlo) == ["segment_spmm_softmax"] * 3
+    messages = 2 * g.num_edges
+    narrow = {int(e) for e in re.findall(r"f32\[(\d+),4\]", hlo)}
+    assert not {e for e in narrow if e >= messages}, narrow
 
 
 def test_compile_cache_follows_env(monkeypatch, tmp_path):
