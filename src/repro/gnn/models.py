@@ -39,9 +39,12 @@ together) — which dispatch on the knob —
             jnp oracle off-TPU, the Pallas one-hot kernel on TPU (its sum
             or softmax combiner). Backward of the sum is a plain gather,
             that of the softmax a recompute from the saved row max
-            (custom_vjp), so gradients match the scatter oracle to
-            allclose, and the forward's O(E) edge hot path of every model
-            is scatter-free under tiled/pallas. (The k-way replica sync
+            (custom_vjp; one reverse kernel pass over the layout where
+            the edge list is its own transpose), so gradients match the
+            scatter oracle to allclose, and the forward's O(E) edge hot
+            path of every model is scatter-free under tiled/pallas. The
+            backward is scatter-free too where the list is its own
+            transpose. (The k-way replica sync
             still scatters into its bucket-sized halo buffers —
             O(replicas), the network path, not the edge hot path.)
   pallas  — like tiled but forces the Pallas kernel (interpreted on CPU).

@@ -239,10 +239,8 @@ class _PartialAggSync(_CodecSync):
         completed as three aggregates: the row max, then the denominator
         and the numerator, each rescaled to the completed max first."""
         a_src, s_dst = scores
-        m, den, num = ops.edge_softmax(
-            z, a_src, s_dst, jnp.concatenate([blk.esrc, blk.edst]),
-            jnp.concatenate([blk.edst, blk.esrc]), z.shape[0],
-            mask=jnp.concatenate([blk.emask, blk.emask]),
+        m, den, num = ops.edge_softmax_symmetric(
+            z, a_src, s_dst, blk.esrc, blk.edst, mask=blk.emask,
             edge_order=blk.agg_order, local_dst=blk.agg_ldst,
             block_tile=blk.agg_btile, backend=backend)
         m_all = self._complete(m, self.reduce_max)

@@ -13,6 +13,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import ref
 from repro.obs.trace import get_tracer
@@ -20,6 +21,9 @@ from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.segment_spmm import SOFTMAX_FLOOR
 from repro.kernels.segment_spmm import segment_softmax as _softmax_pallas
+from repro.kernels.segment_spmm import (
+    segment_softmax_bwd as _softmax_bwd_pallas,
+)
 from repro.kernels.segment_spmm import segment_spmm as _spmm_pallas
 from repro.kernels.tiling import (  # noqa: F401 (re-exported host-layout API)
     DEFAULT_BLOCK_E,
@@ -403,9 +407,11 @@ def _softmax_tile_f(w: int, dh: int) -> int:
 
 
 def _softmax_kernel(num_rows, tile_v, block_e, interpret, z, a_src, s_dst,
-                    layout_src, local_dst, block_tile):
-    """(m, den, num) of the kernel's softmax combiner: [num_rows, H] for m
-    and den, [num_rows, H*dh] for num."""
+                    layout_src, local_dst, block_tile, with_pos=False):
+    """(m, den, num) of the kernel's softmax combiner, [num_rows, H] for m
+    and den and [num_rows, H*dh] for num; `with_pos` adds num_pos and
+    den_pos (the sums over the edges whose score is not negative), as num
+    and den."""
     heads, dh = a_src.shape
     w = heads * dh
     rows_padded, _ = tiled_shape(num_rows, tile_v)
@@ -422,49 +428,139 @@ def _softmax_kernel(num_rows, tile_v, block_e, interpret, z, a_src, s_dst,
         a_rows = a_lanes.reshape(w // tile_f, rows, tile_f)
         s_lanes = jnp.pad(_per_lane(s_dst, dh),
                           ((0, rows_padded - num_rows), (0, 0)))
-        num, den, m = _softmax_pallas(
+        num, den, m, *pos = _softmax_pallas(
             ordered, local_dst, block_tile, a_rows, s_lanes, rows_padded,
             head_dim=dh, block_e=block_e, tile_v=tile_v, tile_f=tile_f,
-            interpret=interpret or not _on_tpu())
+            with_pos=with_pos, interpret=interpret or not _on_tpu())
         head = lambda t: t[:num_rows, ::dh]  # noqa: E731
-        return head(m), head(den), num[:num_rows]
+        out = (head(m), head(den), num[:num_rows])
+        if with_pos:
+            out += (pos[0][:num_rows], head(pos[1]))
+        return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _tiled_softmax(num_rows, tile_v, block_e, interpret, z, a_src, s_dst,
-                   src, dst, mask, layout_src, local_dst, block_tile):
+def _score_grad(g_den, g_num, den, num, den_pos, num_pos):
+    """The destination scores' gradient [n, H] in closed form from the
+    forward's sums: leaky_relu' is 0.2 + 0.8 [score >= 0], so the sum over
+    a row's edges of ds = w (g_num . z_src + g_den) leaky_relu' is
+
+      0.2 (g_num . num + g_den den) + 0.8 (g_num . num_pos + g_den den_pos)
+    """
+    heads = den.shape[1]
+
+    def dot(a, b):
+        return (a * b).reshape(a.shape[0], heads, -1).sum(-1)
+
+    return (0.2 * (dot(g_num, num) + g_den * den)
+            + 0.8 * (dot(g_num, num_pos) + g_den * den_pos))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _tiled_softmax(num_rows, tile_v, block_e, interpret, symmetric, z, a_src,
+                   s_dst, src, dst, mask, layout_src, local_dst, block_tile):
     """The edge softmax through the tiled layout and the kernel's softmax
-    combiner. The layout must drop exactly the masked edges."""
-    del src, dst, mask  # the layout holds them; they feed the backward
+    combiner. The layout must drop exactly the masked edges. Without a
+    gradient the kernel keeps no sums for the backward."""
+    del symmetric, src, dst, mask  # they choose and feed the backward
     return _softmax_kernel(num_rows, tile_v, block_e, interpret, z, a_src,
                            s_dst, layout_src, local_dst, block_tile)
 
 
-def _tiled_softmax_fwd(num_rows, tile_v, block_e, interpret, z, a_src, s_dst,
-                       src, dst, mask, layout_src, local_dst, block_tile):
-    m, den, num = _softmax_kernel(num_rows, tile_v, block_e, interpret, z,
-                                  a_src, s_dst, layout_src, local_dst,
-                                  block_tile)
+def _tiled_softmax_fwd(num_rows, tile_v, block_e, interpret, symmetric, z,
+                       a_src, s_dst, src, dst, mask, layout_src, local_dst,
+                       block_tile):
+    m, den, num, num_pos, den_pos = _softmax_kernel(
+        num_rows, tile_v, block_e, interpret, z, a_src, s_dst, layout_src,
+        local_dst, block_tile, with_pos=True)
     # node-sized residuals and the block's own index arrays only
-    return (m, den, num), (z, a_src, s_dst, src, dst, mask, m)
+    edges = ((layout_src, local_dst, block_tile) if symmetric
+             else (src, dst, mask))
+    return (m, den, num), (z, a_src, s_dst, m, den, num, num_pos, den_pos,
+                           edges)
 
 
 @jax.named_scope("edge.softmax")
-def _tiled_softmax_bwd(num_rows, tile_v, block_e, interpret, res, g):
-    """Recompute each edge's weight from the saved row max (the forward
-    keeps nothing per edge), chunk by chunk over the edges, each chunk
-    one gather of its source rows and one of its destination rows, a
-    scatter-add into the source rows and a narrow one ([chunk, H]) into
-    the destination rows:
+def _tiled_softmax_bwd(num_rows, tile_v, block_e, interpret, symmetric, res,
+                       g):
+    """The gradients with respect to z, a_src and s_dst. Each edge's
+    weight is recomputed from the saved row max (the forward keeps nothing
+    per edge), and with ds = w (g_num[dst] . z_src + g_den[dst])
+    leaky_relu'(pre) for each edge:
 
-      w      = exp(leaky_relu(pre) - m[dst])
-      ds     = w (g_num[dst] . z_src + g_den[dst]) leaky_relu'(pre)
-      dz    += w g_num[dst] + ds a_src              (to the source row)
-      ds_dst += ds                                  (to the destination row)
-      da_src += ds z_src
-    """
-    z, a_src, s_dst, src, dst, mask, m = map(jnp.asarray, res)
+      dz[src] += w g_num[dst] + ds a_src
+      da_src  += ds z_src
+      ds_dst   = `_score_grad` of the forward's sums (no edge pass)
+
+    The first two take one kernel pass over the forward's layout where the
+    edge list is its own transpose (`_softmax_reverse`), else a chunked
+    loop of gathers and scatter-adds (`_softmax_chunks`)."""
+    z, a_src, s_dst, m, den, num, num_pos, den_pos, edges = res
     _, g_den, g_num = g
+    table = jnp.concatenate([g_num, s_dst, m, g_den], axis=1)
+    if symmetric:
+        get_tracer().add("edge.softmax.reverse_pass", 1)
+        dz, da = _softmax_reverse(num_rows, tile_v, block_e, interpret, z,
+                                  a_src, table, *edges)
+    else:
+        dz, da = _softmax_chunks(num_rows, z, a_src, table, *edges)
+    dsd = _score_grad(g_den, g_num, den, num, den_pos, num_pos)
+    return dz, da, dsd, None, None, None, None, None, None
+
+
+_tiled_softmax.defvjp(_tiled_softmax_fwd, _tiled_softmax_bwd)
+
+
+def _softmax_reverse(num_rows, tile_v, block_e, interpret, z, a_src, table,
+                     layout_src, local_dst, block_tile):
+    """dz and da_src in one pass of `segment_softmax_bwd` over the
+    forward's layout. The edge list is its own transpose, so the layout
+    edges of row r are the edges out of r: one gather of the neighbours'
+    `table` rows [g_num | s_dst | m | g_den] into layout order, and the
+    kernel sums w g_num and ds over each row's reverse edges."""
+    heads, dh = a_src.shape
+    w = heads * dh
+    rows_padded, _ = tiled_shape(num_rows, tile_v)
+    tile_f = _softmax_tile_f(w, dh)
+    per_tile = tile_f // dh
+    if tile_f == w:
+        # one feature tile: the tail follows g_num in the tile's own block
+        tail, tail_block, first = table.shape[1], 0, w
+    else:
+        # the tail in a lane block of its own after g_num
+        assert 3 * heads <= DEFAULT_TILE_F, (
+            f"{heads} heads: their 3 score columns overflow a lane block")
+        tail, tail_block, first = DEFAULT_TILE_F, w // DEFAULT_TILE_F, 0
+        # padded before the gather, behind a barrier: XLA would otherwise
+        # pad the gathered copy, and hold both
+        table = jax.lax.optimization_barrier(
+            jnp.pad(table, ((0, 0), (0, tail - 3 * heads))))
+    # select[ft, k*per_tile + h] picks quantity k of tile ft's head h;
+    # rows padded to a sublane multiple pick nothing
+    k, h = np.divmod(np.arange(3 * per_tile), per_tile)
+    cols = first + k * heads + np.arange(w // tile_f)[:, None] * per_tile + h
+    cols = np.pad(cols, ((0, 0), (0, -cols.shape[1] % 8)),
+                  constant_values=-1)
+    select = jnp.asarray(cols[:, :, None] == np.arange(tail), jnp.float32)
+    s_src = attention_scores(z, a_src)
+    pad = ((0, rows_padded - num_rows), (0, 0))
+    with jax.named_scope("edge.reorder"):
+        ordered = table[layout_src]
+    part, sums = _softmax_bwd_pallas(
+        ordered, local_dst, block_tile, select, jnp.pad(z, pad),
+        jnp.pad(_per_lane(s_src, dh), pad), rows_padded, head_dim=dh,
+        tail_block=tail_block, block_e=block_e, tile_v=tile_v,
+        tile_f=tile_f, interpret=interpret or not _on_tpu())
+    ds = sums[:num_rows, ::dh]
+    dz = part[:num_rows] + _per_lane(ds, dh) * a_src.reshape(1, w)
+    da = jnp.einsum("nh,nhd->hd", ds, z.reshape(-1, heads, dh),
+                    precision=jax.lax.Precision.HIGHEST)
+    return dz, da
+
+
+def _softmax_chunks(num_rows, z, a_src, table, src, dst, mask):
+    """dz and da_src chunk by chunk over the edges, each chunk one gather
+    of its source rows [z | s_src], one of its destination rows
+    [g_num | s_dst | m | g_den] and a scatter-add into the source rows."""
     heads, dh = a_src.shape
     w_all = heads * dh
     e = src.shape[0]
@@ -474,17 +570,13 @@ def _tiled_softmax_bwd(num_rows, tile_v, block_e, interpret, res, g):
     dst_c = jnp.pad(dst, (0, pad), constant_values=num_rows).reshape(
         -1, chunk)
     mask_c = jnp.pad(mask, (0, pad)).reshape(-1, chunk)
-    # one row a source: [z | s_src]; one a destination (and a zero pad
-    # row): [g_num | s_dst | m | g_den]
     src_rows = jnp.concatenate([z, attention_scores(z, a_src)], axis=1)
-    dst_rows = jnp.pad(jnp.concatenate([g_num, s_dst, m, g_den], axis=1),
-                       ((0, 1), (0, 0)))
+    dst_rows = jnp.pad(table, ((0, 1), (0, 0)))  # a zero pad row
 
     def body(carry, xs):
-        dz, dsd, da = carry
+        dz, da = carry
         s_i, d_i, k_i = xs
-        d_i = jnp.minimum(d_i, num_rows)
-        sr, dr = src_rows[s_i], dst_rows[d_i]
+        sr, dr = src_rows[s_i], dst_rows[jnp.minimum(d_i, num_rows)]
         zs = sr[:, :w_all].reshape(-1, heads, dh)
         gn = dr[:, :w_all].reshape(-1, heads, dh)
         sd, md, gd = (dr[:, w_all + i * heads:w_all + (i + 1) * heads]
@@ -496,17 +588,12 @@ def _tiled_softmax_bwd(num_rows, tile_v, block_e, interpret, res, g):
               * jnp.where(pre >= 0, 1.0, 0.2))
         dzs = w[:, :, None] * gn + ds[:, :, None] * a_src
         dz = dz.at[s_i].add(dzs.reshape(-1, w_all))
-        dsd = dsd.at[d_i].add(ds)  # the pad row num_rows takes the pad's 0s
-        return (dz, dsd, da + jnp.einsum("eh,ehd->hd", ds, zs)), None
+        return (dz, da + jnp.einsum("eh,ehd->hd", ds, zs)), None
 
-    (dz, dsd, da), _ = jax.lax.scan(
-        body, (jnp.zeros_like(z), jnp.zeros((num_rows + 1, heads), z.dtype),
-               jnp.zeros_like(a_src)),
+    (dz, da), _ = jax.lax.scan(
+        body, (jnp.zeros_like(z), jnp.zeros_like(a_src)),
         (src_c, dst_c, mask_c))
-    return dz, da, dsd[:num_rows], None, None, None, None, None, None
-
-
-_tiled_softmax.defvjp(_tiled_softmax_fwd, _tiled_softmax_bwd)
+    return dz, da
 
 
 def edge_softmax(
@@ -542,12 +629,54 @@ def edge_softmax(
     tiled — one pass of the kernel's softmax combiner over the
     `prepare_tiled_edges` layout (built with valid=mask) on a TPU, the
     oracle elsewhere, its source rows gathered straight into layout
-    order (`src` composed with `edge_order`); pallas — the kernel everywhere (interpreted off a
-    TPU). The kernel path's backward recomputes the edge weights from the
-    saved row max and keeps nothing per edge between the passes. Scopes:
-    `edge.reorder` (the rows' gather into layout order) and `edge.softmax`
-    (the kernel, its backward, the oracle); counter `edge.softmax.calls`.
+    order (`src` composed with `edge_order`); pallas — the kernel
+    everywhere (interpreted off a TPU). The kernel path's backward
+    recomputes the edge weights from the saved row max and keeps nothing
+    per edge between the passes: a chunked loop of scatter-adds here, one
+    reverse kernel pass for the lists `edge_softmax_symmetric` takes.
+    Scopes: `edge.reorder` (the rows' gathers into layout order) and
+    `edge.softmax` (the kernel, its backward, the oracle); counters
+    `edge.softmax.calls` (one a traced aggregate) and
+    `edge.softmax.reverse_pass` (one a traced backward that takes the
+    reverse pass).
     """
+    return _edge_softmax(z, a_src, s_dst, src, dst, num_rows, mask,
+                         (edge_order, local_dst, block_tile), backend,
+                         False, tile_v, block_e, interpret)
+
+
+def edge_softmax_symmetric(
+    z: jnp.ndarray,        # [n, H*dh] the rows (heads concatenated)
+    a_src: jnp.ndarray,    # [H, dh] source attention vectors
+    s_dst: jnp.ndarray,    # [n, H] destination scores
+    esrc: jnp.ndarray,     # [E] int32 one end of each edge (< n)
+    edst: jnp.ndarray,     # [E] int32 its other end (< n)
+    *,
+    mask: jnp.ndarray,     # [E] bool; False edges take no part
+    edge_order: jnp.ndarray | None = None,
+    local_dst: jnp.ndarray | None = None,
+    block_tile: jnp.ndarray | None = None,
+    backend: str = "scatter",
+    tile_v: int = DEFAULT_TILE_V,
+    block_e: int = DEFAULT_BLOCK_E,
+    interpret: bool = False,
+) -> tuple:
+    """`edge_softmax` over both directions of every edge, into the rows of
+    z: the list [esrc; edst] -> [edst; esrc] with mask [mask; mask], and
+    its layout built so (`prepare_tiled_edges` of [edst; esrc] with that
+    valid). The list is its own transpose by construction, so a layout
+    edge of row r with neighbour n is also the edge r -> n, and the kernel
+    path's backward is one reverse pass over the forward's layout
+    (`segment_spmm_softmax_bwd`), with no scatter."""
+    return _edge_softmax(
+        z, a_src, s_dst, jnp.concatenate([esrc, edst]),
+        jnp.concatenate([edst, esrc]), z.shape[0],
+        jnp.concatenate([mask, mask]), (edge_order, local_dst, block_tile),
+        backend, True, tile_v, block_e, interpret)
+
+
+def _edge_softmax(z, a_src, s_dst, src, dst, num_rows, mask, layout,
+                  backend, symmetric, tile_v, block_e, interpret):
     if backend not in AGG_BACKENDS:
         raise ValueError(f"unknown aggregate backend {backend!r}; "
                          f"options: {AGG_BACKENDS}")
@@ -557,14 +686,15 @@ def edge_softmax(
         with jax.named_scope("edge.softmax"):
             return _softmax_scatter(z, a_src, s_dst, src, dst, mask,
                                     num_rows)
+    edge_order, local_dst, block_tile = layout
     assert (edge_order is not None and local_dst is not None
             and block_tile is not None), (
         "tiled/pallas backends need the prepare_tiled_edges layout")
     layout_src = jnp.concatenate([src, jnp.zeros((1,), src.dtype)])[
         edge_order]
-    m, den, num = _tiled_softmax(num_rows, tile_v, block_e, interpret, z,
-                                 a_src, s_dst, src, dst, mask, layout_src,
-                                 local_dst, block_tile)
+    m, den, num = _tiled_softmax(num_rows, tile_v, block_e, interpret,
+                                 symmetric, z, a_src, s_dst, src, dst, mask,
+                                 layout_src, local_dst, block_tile)
     return jax.lax.stop_gradient(m), den, num
 
 

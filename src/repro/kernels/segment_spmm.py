@@ -45,7 +45,23 @@ softmax per row and head as in flash attention:
 
 The source scores come from z by one small matmul with the heads' `a_src`
 rows, so nothing per edge but z is read. Each head's max and denominator
-are kept repeated over its lanes, so the outputs share one block.
+are kept repeated over its lanes, so the outputs share one block. Where
+a gradient is taken, two more accumulators, num_pos and den_pos, sum the
+same over the edges whose score is not negative: the destination scores'
+gradient is a node-sized expression of the four sums (ops.py).
+
+Its backward (`segment_softmax_bwd`, for an edge list that is its own
+transpose) runs on the same grid and layout: an edge of row r with
+neighbour n stands for the reverse edge r -> n, so the gradient with
+respect to the source row r is a sum over the row tile's own edges.
+Per head and block it rescores the (row, edge) pairs from the neighbours'
+gathered [g_num | s_dst | m | g_den] rows and the row tile's z, and folds
+
+  w     = exp(leaky_relu(s_src[r] + s_dst[n]) - m[n])
+  part += w @ g_num                              (MXU)
+  sums += row sums of w (g_num[n] . z[r] + g_den[n]) leaky_relu'
+
+into two resident accumulators, with no scatter.
 
 The jit'd wrapper (ops.py) validates shapes and falls back to the pure-jnp
 oracle (ref.py) on non-TPU backends; interpret=True is used by the tests.
@@ -221,9 +237,15 @@ def segment_spmm(
     )(block_tile.astype(jnp.int32), dst_blocks, messages)
 
 
+def _mxu(a, b):
+    """a @ b on the MXU at the default precision (bfloat16 operands on a
+    TPU), accumulated in float32."""
+    return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
+
+
 def _segment_softmax_kernel(tile_ref, dst_ref, z_ref, a_ref, sdst_ref,
-                            num_ref, den_ref, m_ref, *, block_e, tile_v,
-                            head_dim):
+                            num_ref, den_ref, m_ref, *pos_refs,
+                            block_e, tile_v, head_dim):
     """One grid step of the softmax combiner: fold one edge block into its
     row tile's accumulators.
 
@@ -233,13 +255,17 @@ def _segment_softmax_kernel(tile_ref, dst_ref, z_ref, a_ref, sdst_ref,
                                 head's lanes
     num_ref, den_ref, m_ref: [tile_v, tile_f] resident accumulators; den
                                 and m repeated over each head's lanes
+    pos_refs: none, or (num_pos, den_pos) [tile_v, tile_f]: num and den
+                                over the edges whose score is not negative
+                                (the backward's leaky_relu' is 1 there),
+                                rescaled alike
     """
     b = pl.program_id(1)
 
     @pl.when((b == 0) | (tile_ref[b] != tile_ref[jnp.maximum(b - 1, 0)]))
     def _init():
-        num_ref[...] = jnp.zeros_like(num_ref)
-        den_ref[...] = jnp.zeros_like(den_ref)
+        for ref in (num_ref, den_ref, *pos_refs):
+            ref[...] = jnp.zeros_like(ref)
         m_ref[...] = jnp.full_like(m_ref, SOFTMAX_FLOOR)
 
     tile_f = z_ref.shape[1]
@@ -258,8 +284,9 @@ def _segment_softmax_kernel(tile_ref, dst_ref, z_ref, a_ref, sdst_ref,
     z_head = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) // head_dim
     # per head: [tile_v, 1] columns (rescale, new max, weight sums), and
     # the block's [tile_v, tile_f] products, all heads' lanes together
-    cols = {"scale": None, "m": None, "den": None}
+    cols = {}
     part = jnp.zeros(num_ref.shape, jnp.float32)
+    part_pos = jnp.zeros(num_ref.shape, jnp.float32)
     for h in range(heads):
         if heads == 1:
             col = lambda t: t[:, :1]  # noqa: E731 (every lane holds it)
@@ -274,16 +301,24 @@ def _segment_softmax_kernel(tile_ref, dst_ref, z_ref, a_ref, sdst_ref,
         m_h = col(m_old)
         mn_h = jnp.maximum(m_h, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - mn_h)                    # 0 where no edge hits
-        part = part + jax.lax.dot(p, zh, preferred_element_type=jnp.float32)
+        part = part + _mxu(p, zh)
         got = {"scale": jnp.exp(m_h - mn_h), "m": mn_h,
                "den": jnp.sum(p, axis=1, keepdims=True)}
+        if pos_refs:
+            p_pos = jnp.where(pre >= 0, p, 0.0)
+            part_pos = part_pos + _mxu(p_pos, zh)
+            got["dpos"] = jnp.sum(p_pos, axis=1, keepdims=True)
         for k, v in got.items():
             cols[k] = v if heads == 1 else jnp.where(
-                lane_head == h, v, 0.0 if cols[k] is None else cols[k])
+                lane_head == h, v, cols.get(k, 0.0))
     scale = cols["scale"]
     num_ref[...] = num_ref[...] * scale + part
     den_ref[...] = den_ref[...] * scale + cols["den"]
     m_ref[...] = jnp.broadcast_to(cols["m"], m_ref.shape)
+    if pos_refs:
+        npos_ref, dpos_ref = pos_refs
+        npos_ref[...] = npos_ref[...] * scale + part_pos
+        dpos_ref[...] = dpos_ref[...] * scale + cols["dpos"]
 
 
 def segment_softmax(
@@ -298,20 +333,24 @@ def segment_softmax(
     block_e: int = DEFAULT_BLOCK_E,
     tile_v: int = DEFAULT_TILE_V,
     tile_f: int = DEFAULT_TILE_F,
+    with_pos: bool = False,
     interpret: bool = False,
 ) -> tuple:
     """The softmax combiner on the layout `segment_spmm` takes. Returns
-    (num, den, m), each [num_rows, W]; den and m are repeated over each
-    head's `head_dim` lanes. A feature tile holds whole heads (`tile_f` is
+    (num, den, m), each [num_rows, W], den and m repeated over each head's
+    `head_dim` lanes; `with_pos` (static) adds (num_pos, den_pos), num and
+    den over the edges whose score is not negative, which the backward's
+    destination scores need. A feature tile holds whole heads (`tile_f` is
     a multiple of `head_dim`), and `a_rows[ft]` holds in row h the `a_src`
     of the tile's h-th head, in that head's lanes. Rows no edge reaches
-    come back as (0, 0, SOFTMAX_FLOOR)."""
+    come back as (0, 0, SOFTMAX_FLOOR), and 0 for the two sums."""
     e, w = z.shape
     assert num_rows % tile_v == 0, (num_rows, tile_v)
     assert w % tile_f == 0 and tile_f % head_dim == 0, (w, tile_f, head_dim)
     n_blocks = block_tile.shape[0]
     assert e == n_blocks * block_e, (e, n_blocks, block_e)
     rows = a_rows.shape[1]
+    outputs = 5 if with_pos else 3
     kernel = functools.partial(_segment_softmax_kernel, block_e=block_e,
                                tile_v=tile_v, head_dim=head_dim)
     dst_blocks = local_dst.reshape(n_blocks, 1, block_e)
@@ -327,15 +366,156 @@ def segment_softmax(
                          lambda ft, b, tiles: (ft, 0, 0)),
             row_spec,
         ],
-        out_specs=[row_spec] * 3,
+        out_specs=[row_spec] * outputs,
     )
     out = jax.ShapeDtypeStruct((num_rows, w), jnp.float32)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[out] * 3,
+        out_shape=[out] * outputs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="segment_spmm_softmax",
     )(block_tile.astype(jnp.int32), dst_blocks, z, a_rows, s_dst)
+
+
+def _segment_softmax_bwd_kernel(tile_ref, dst_ref, g_ref, t_ref, sel_ref,
+                                z_ref, ssrc_ref, part_ref, sums_ref, *,
+                                block_e, tile_v, head_dim):
+    """One grid step of the reverse pass: the layout's edges of a row tile,
+    read as the reverse edges out of its rows, folded into the row tile.
+
+    An edge of row r with neighbour n stands for the edge r -> n, whose
+    weight is w = exp(leaky_relu(s_src[r] + s_dst[n]) - m[n]).
+
+    g_ref:    [block_e, >= tile_f] the neighbours' rows; lanes [0, tile_f)
+                                hold g_num of the tile's heads
+    t_ref:    [block_e, tail]   the neighbours' [s_dst | m | g_den] lanes
+    sel_ref:  [rows, tail]      row k*heads + h picks quantity k (s_dst,
+                                m, g_den) of the tile's head h
+    z_ref, ssrc_ref: [tile_v, tile_f] the row tile's z and its source
+                                scores, repeated over each head's lanes
+    part_ref: [tile_v, tile_f]  resident: sum of w g_num[n]
+    sums_ref: [tile_v, tile_f]  resident: sum of
+                                ds = w (g_num[n] . z[r] + g_den[n]) leaky_relu',
+                                repeated over each head's lanes
+    """
+    b = pl.program_id(1)
+
+    @pl.when((b == 0) | (tile_ref[b] != tile_ref[jnp.maximum(b - 1, 0)]))
+    def _init():
+        part_ref[...] = jnp.zeros_like(part_ref)
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    tile_f = z_ref.shape[1]
+    heads = tile_f // head_dim
+    z = z_ref[...]
+    g = g_ref[...][:, :tile_f]
+    hits = (jax.lax.broadcasted_iota(jnp.int32, (tile_v, block_e), 0)
+            == dst_ref[...])
+    # the neighbours' per-head values, one row per (quantity, head):
+    # [rows, block_e], exact (a 0/1 selection at full precision)
+    per_edge = jax.lax.dot_general(
+        sel_ref[...], t_ref[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    s_src = ssrc_ref[...]
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (tile_v, tile_f),
+                                         1) // head_dim
+    g_head = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1) // head_dim
+    part = jnp.zeros(part_ref.shape, jnp.float32)
+    sums = None
+    for h in range(heads):
+        if heads == 1:
+            col = lambda t: t[:, :1]  # noqa: E731 (every lane holds it)
+            zh, gh = z, g
+        else:
+            col = lambda t: jnp.max(  # noqa: E731 (head h's lanes)
+                jnp.where(lane_head == h, t, SOFTMAX_FLOOR), axis=1,
+                keepdims=True)
+            zh = jnp.where(lane_head == h, z, 0.0)
+            gh = jnp.where(g_head == h, g, 0.0)
+        s_dst, m, g_den = (per_edge[k * heads + h:k * heads + h + 1, :]
+                           for k in range(3))
+        pre = col(s_src) + s_dst
+        w = jnp.exp(jnp.where(hits, jnp.maximum(pre, 0.2 * pre) - m,
+                              -jnp.inf))            # 0 where no edge hits
+        # g_num[n] . z[r] of head h for every (row, edge) pair
+        gz = jax.lax.dot_general(
+            zh, g, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        ds = w * (gz + g_den) * jnp.where(pre >= 0, 1.0, 0.2)
+        part = part + _mxu(w, gh)
+        d_h = jnp.sum(ds, axis=1, keepdims=True)
+        sums = d_h if heads == 1 else jnp.where(
+            lane_head == h, d_h, 0.0 if sums is None else sums)
+    part_ref[...] += part
+    sums_ref[...] += jnp.broadcast_to(sums, sums_ref.shape)
+
+
+def segment_softmax_bwd(
+    rows: jnp.ndarray,        # [E, T] neighbour rows, pre-sorted by dst tile
+    local_dst: jnp.ndarray,   # [E] int32 row id WITHIN the edge's row tile
+    block_tile: jnp.ndarray,  # [E / block_e] int32 row tile of each block
+    select: jnp.ndarray,      # [W / tile_f, rows, tail] per-tile selector
+    z: jnp.ndarray,           # [num_rows, W] the rows' z
+    s_src: jnp.ndarray,       # [num_rows, W] source scores over head lanes
+    num_rows: int,
+    *,
+    head_dim: int,
+    tail_block: int,
+    block_e: int = DEFAULT_BLOCK_E,
+    tile_v: int = DEFAULT_TILE_V,
+    tile_f: int = DEFAULT_TILE_F,
+    interpret: bool = False,
+) -> tuple:
+    """The softmax combiner's backward in one pass over the forward's own
+    layout, for an edge list that is its own transpose: each row tile's
+    edges are the reverse edges out of its rows, so both sums of the
+    gradient with respect to a source row fold into resident row tiles,
+    with no scatter. `rows[e]` is the layout edge's neighbour's
+    [g_num | s_dst | m | g_den] (g_num in lanes [0, W)); its tail is the
+    `tail`-lane block number `tail_block`, the block [0, T) itself where one
+    feature tile spans the rows (T = tail). Returns (part, sums), each
+    [num_rows, W]: the sum over a row's reverse edges of w g_num and of
+    ds, the latter repeated over each head's lanes."""
+    e, t_w = rows.shape
+    w = z.shape[1]
+    assert num_rows % tile_v == 0, (num_rows, tile_v)
+    assert w % tile_f == 0 and tile_f % head_dim == 0, (w, tile_f, head_dim)
+    n_blocks = block_tile.shape[0]
+    assert e == n_blocks * block_e, (e, n_blocks, block_e)
+    sel_rows, tail = select.shape[1:]
+    kernel = functools.partial(_segment_softmax_bwd_kernel, block_e=block_e,
+                               tile_v=tile_v, head_dim=head_dim)
+    dst_blocks = local_dst.reshape(n_blocks, 1, block_e)
+    row_spec = pl.BlockSpec((tile_v, tile_f),
+                            lambda ft, b, tiles: (tiles[b], ft))
+    g_width = t_w if w == tile_f else tile_f
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(w // tile_f, n_blocks),
+        in_specs=[
+            pl.BlockSpec((None, 1, block_e), lambda ft, b, tiles: (b, 0, 0)),
+            pl.BlockSpec((block_e, g_width), lambda ft, b, tiles: (b, ft)),
+            pl.BlockSpec((block_e, tail),
+                         lambda ft, b, tiles: (b, tail_block)),
+            pl.BlockSpec((None, sel_rows, tail),
+                         lambda ft, b, tiles: (ft, 0, 0)),
+            row_spec,
+            row_spec,
+        ],
+        out_specs=[row_spec] * 2,
+    )
+    out = jax.ShapeDtypeStruct((num_rows, w), jnp.float32)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[out] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="segment_spmm_softmax_bwd",
+    )(block_tile.astype(jnp.int32), dst_blocks, rows, rows, select, z, s_src)

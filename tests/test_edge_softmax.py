@@ -165,6 +165,322 @@ def test_edge_softmax_calls_counted_when_traced(or_graph, node_data, sync,
     assert tr.total("edge.softmax.calls") is None
 
 
+def _symmetric(rng, *, n, e, heads, dh, reach, hub=None):
+    """An edge list that is its own transpose, [a; b] -> [b; a], a tenth of
+    its pairs masked (both directions), over rows [0, reach): the rows
+    past it are isolated. `hub` joins one row to as many more."""
+    a = rng.integers(0, reach, e).astype(np.int32)
+    b = rng.integers(0, reach, e).astype(np.int32)
+    if hub is not None:
+        a = np.concatenate([a, np.zeros(hub, np.int32)])
+        b = np.concatenate([b, rng.integers(0, reach, hub).astype(np.int32)])
+    keep = rng.random(a.shape[0]) >= 0.1
+    z = rng.normal(size=(n, heads * dh)).astype(np.float32)
+    att = (rng.normal(size=(heads, dh)) / np.sqrt(dh)).astype(np.float32)
+    s_dst = rng.normal(size=(n, heads)).astype(np.float32)
+    return (z, att, s_dst, np.concatenate([a, b]), np.concatenate([b, a]),
+            np.concatenate([keep, keep]))
+
+
+def _reverse_pass_grads(z, a, s_dst, src, dst, mask, rows):
+    """Gradients of the reverse-pass backward and of the oracle's
+    autodiff, for the same cotangents of (den, num); the reverse pass
+    counted once."""
+    from repro.obs.trace import tracing
+
+    order, ldst, btile, _ = ops.prepare_tiled_edges(dst, rows, valid=mask)
+    layout = dict(edge_order=jnp.asarray(order.astype(np.int32)),
+                  local_dst=jnp.asarray(ldst), block_tile=jnp.asarray(btile))
+    g_rng = np.random.default_rng(2)
+    g_den = jnp.asarray(g_rng.normal(size=(rows, a.shape[0])), jnp.float32)
+    g_num = jnp.asarray(g_rng.normal(size=(rows, z.shape[1])), jnp.float32)
+
+    half = src.shape[0] // 2
+
+    def grads(softmax):
+        def f(z, a, s_dst):
+            _, den, num = softmax(z, a, s_dst)
+            return (den * g_den).sum() + (num * g_num).sum()
+
+        return jax.grad(f, argnums=(0, 1, 2))(z, a, s_dst)
+
+    want = grads(lambda *x: ops.edge_softmax(
+        *x, jnp.asarray(src), jnp.asarray(dst), rows, mask=jnp.asarray(mask)))
+    with tracing() as tr:
+        got = grads(lambda *x: ops.edge_softmax_symmetric(
+            *x, jnp.asarray(src[:half]), jnp.asarray(dst[:half]),
+            mask=jnp.asarray(mask[:half]), backend="pallas", **layout))
+    assert tr.total("edge.softmax.reverse_pass") == 1
+    return btile, got, want
+
+
+def _assert_grads(got, want):
+    for name, x, y in zip(("dz", "da", "ds_dst"), got, want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("heads,dh", [(4, 128), (4, 4)])
+def test_reverse_pass_matches_oracle(heads, dh):
+    """A symmetric list with masked pairs and isolated rows, and a hub row
+    whose edges fill several blocks of its row tile, over three row tiles:
+    dz, da and ds_dst as the oracle's autodiff gives them."""
+    rng = np.random.default_rng(11 * dh)
+    rows = 700
+    problem = _symmetric(rng, n=rows, e=1200, heads=heads, dh=dh, reach=560,
+                         hub=3 * ops.DEFAULT_BLOCK_E)
+    btile, got, want = _reverse_pass_grads(*problem, rows)
+    assert len(set(btile)) == 3
+    assert (np.asarray(btile) == 0).sum() >= 3
+    _assert_grads(got, want)
+    isolated = np.asarray(got[0])[560:]
+    assert (isolated == 0).all()
+
+
+@pytest.mark.parametrize("heads,dh", [(4, 128), (4, 4)])
+def test_reverse_pass_ties_at_zero(heads, dh):
+    """Scores exactly 0, where leaky_relu' switches: zero rows with zero
+    destination scores tie every score of their edges at 0, and the
+    derivative there is 1 in the reverse pass, in the closed-form
+    ds_dst and in the oracle's autodiff."""
+    rng = np.random.default_rng(13)
+    rows = 300
+    z, a, s_dst, src, dst, mask = _symmetric(rng, n=rows, e=900, heads=heads,
+                                             dh=dh, reach=rows)
+    z[::2] = 0.0
+    s_dst[::2] = 0.0
+    pre = (np.einsum("nhd,hd->nh", z.reshape(rows, heads, dh), a)[src]
+           + s_dst[dst])
+    assert (pre[mask] == 0).sum() > 100
+    _, got, want = _reverse_pass_grads(z, a, s_dst, src, dst, mask, rows)
+    _assert_grads(got, want)
+
+
+def _bf16_mxu(a, b):
+    """The MXU's product at the default precision: bfloat16 operands,
+    float32 accumulation."""
+    return jax.lax.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+
+
+@pytest.mark.parametrize("heads,dh", [(4, 128), (4, 4)])
+def test_reverse_pass_error_at_bf16_products(monkeypatch, heads, dh):
+    """On a TPU the kernels' default-precision products (P @ z forward,
+    w @ g_num backward) take bfloat16 operands. With the interpreted
+    kernels' products rounded so, a GAT layer's gradient through the
+    reverse pass and the closed-form ds_dst errs from the float32
+    oracle's, leaf by leaf (z, a_src, a_dst), by at most 1.5x what the
+    chunked loop of float32 per-edge products and scatter-adds into the
+    source and destination rows errs, fed by the same rounded forward.
+    (w @ g_num in bfloat16 adds to z's error; the closed form, consistent
+    with the rounded num it is taken from, takes from a_dst's.)"""
+    from repro.kernels import segment_spmm
+
+    rng = np.random.default_rng(19)
+    rows, e = 600, 3000
+    z, a_src, _, src, dst, mask = _symmetric(rng, n=rows, e=e, heads=heads,
+                                             dh=dh, reach=rows)
+    a_dst = (rng.normal(size=(heads, dh)) / np.sqrt(dh)).astype(np.float32)
+    g_out = jnp.asarray(rng.normal(size=(rows, heads * dh)), jnp.float32)
+    order, ldst, btile, _ = ops.prepare_tiled_edges(dst, rows, valid=mask)
+    layout = dict(edge_order=jnp.asarray(order.astype(np.int32)),
+                  local_dst=jnp.asarray(ldst), block_tile=jnp.asarray(btile))
+    src, dst, mask = map(jnp.asarray, (src, dst, mask))
+
+    def layer_grads(softmax):
+        """gat_layer's attention and quotient around `softmax`."""
+        def loss(z, a_src, a_dst):
+            s_dst = ops.attention_scores(z, a_dst)
+            e_self = jax.nn.leaky_relu(
+                ops.attention_scores(z, a_src) + s_dst, 0.2)
+            _, den, num = ops.softmax_merge(softmax(z, a_src, s_dst),
+                                            ops.softmax_edge(e_self, z))
+            out = num.reshape(rows, heads, dh) / den[:, :, None]
+            return (out.reshape(rows, -1) * g_out).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(z, a_src, a_dst)
+
+    def reverse_pass(z, a, s_dst):
+        return ops.edge_softmax_symmetric(
+            z, a, s_dst, src[:e], dst[:e], mask=mask[:e], backend="pallas",
+            **layout)
+
+    @jax.custom_vjp
+    def chunk_loop(z, a, s_dst):
+        return ops.edge_softmax(z, a, s_dst, src, dst, rows, mask=mask,
+                                backend="pallas", **layout)
+
+    def chunk_loop_fwd(z, a, s_dst):
+        out = chunk_loop(z, a, s_dst)
+        return out, (z, a, s_dst, out)
+
+    def chunk_loop_bwd(res, g):
+        z, a, s_dst, (m, den, num) = res
+        _, g_den, g_num = g
+        table = jnp.concatenate([g_num, s_dst, m, g_den], axis=1)
+        dz, da = ops._softmax_chunks(rows, z, a, table, src, dst, mask)
+        pre = ops.attention_scores(z, a)[src] + s_dst[dst]
+        w = jnp.exp(jnp.where(mask[:, None],
+                              jax.nn.leaky_relu(pre, 0.2) - m[dst], -jnp.inf))
+        gz = jnp.einsum("ehd,ehd->eh", g_num[dst].reshape(-1, heads, dh),
+                        z[src].reshape(-1, heads, dh))
+        ds = w * (gz + g_den[dst]) * jnp.where(pre >= 0, 1.0, 0.2)
+        return dz, da, jnp.zeros_like(s_dst).at[dst].add(ds)
+
+    chunk_loop.defvjp(chunk_loop_fwd, chunk_loop_bwd)
+    want = layer_grads(lambda z, a, s_dst: ops.edge_softmax(
+        z, a, s_dst, src, dst, rows, mask=mask))
+    monkeypatch.setattr(segment_spmm, "_mxu", _bf16_mxu)
+    got = layer_grads(reverse_pass)
+    old = layer_grads(chunk_loop)
+    for name, x, y, w in zip(("z", "a_src", "a_dst"), got, old, want):
+        err, err_old = (float(jnp.linalg.norm(v - w) / jnp.linalg.norm(w))
+                        for v in (x, y))
+        assert 1e-5 < err_old < 1e-2, (name, err_old)
+        assert err <= 1.5 * err_old, (name, err, err_old)
+
+
+def test_kernel_keeps_the_backward_sums_only_for_a_gradient():
+    """The forward alone runs the three-output kernel; the forward of a
+    gradient the five-output one, and the reverse pass its two."""
+    rng = np.random.default_rng(23)
+    z, a, s_dst, src, dst, mask = _symmetric(rng, n=300, e=600, heads=4,
+                                             dh=4, reach=300)
+    order, ldst, btile, _ = ops.prepare_tiled_edges(dst, 300, valid=mask)
+
+    def forward(z):
+        return ops.edge_softmax_symmetric(
+            z, jnp.asarray(a), jnp.asarray(s_dst), jnp.asarray(src[:600]),
+            jnp.asarray(dst[:600]), mask=jnp.asarray(mask[:600]),
+            edge_order=jnp.asarray(order.astype(np.int32)),
+            local_dst=jnp.asarray(ldst), block_tile=jnp.asarray(btile),
+            backend="pallas")
+
+    def outputs(jaxpr):
+        """Output counts of the pallas calls, nested jaxprs included."""
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(len(eqn.outvars))
+            for v in eqn.params.values():
+                sub = getattr(v, "jaxpr", v)
+                if hasattr(sub, "eqns"):
+                    found += outputs(sub)
+        return found
+
+    assert outputs(jax.make_jaxpr(forward)(z).jaxpr) == [3]
+    grad = jax.grad(lambda z: forward(z)[2].sum())
+    assert sorted(outputs(jax.make_jaxpr(grad)(z).jaxpr)) == [2, 5]
+
+
+@pytest.mark.parametrize("heads,dh", [(4, 128), (4, 4)])
+def test_closed_form_score_grad_on_a_chunk_loop(heads, dh):
+    """A list that is not its own transpose (a ring chunk's shape: sources
+    in one block of rows, destinations in another) keeps the chunked
+    loop, with ds_dst now in closed form from the forward's sums; some
+    scores sit exactly at 0."""
+    rng = np.random.default_rng(17)
+    rows, e = 400, 2500
+    z, a, s_dst, _, _, _ = _problem(rng, n=rows, rows=rows, e=e, heads=heads,
+                                    dh=dh)
+    src = rng.integers(200, rows, e).astype(np.int32)
+    dst = rng.integers(0, 200, e).astype(np.int32)
+    mask = rng.random(e) >= 0.1
+    z[200::3] = 0.0
+    s_dst[::3] = 0.0
+    _compare(z, a, s_dst, src, dst, mask, rows)
+
+
+def test_closed_form_score_grad_on_a_minibatch_block():
+    """The mini-batch MFGs are not their own transpose: GAT's mini-batch
+    loss on the interpreted kernel takes no reverse pass, and its
+    gradients (ds_dst in closed form) match the scatter oracle's."""
+    from repro.core.vertex_partition import partition_vertices
+    from repro.gnn.minibatch import MiniBatchTrainer, minibatch_loss
+    from repro.obs.trace import tracing
+
+    from repro.core.graph import paper_graph
+
+    g = paper_graph("OR", scale=0.02, seed=0)
+    rng = np.random.default_rng(0)
+    feats = rng.normal(size=(g.num_vertices, 16)).astype(np.float32)
+    labels = rng.integers(0, 5, g.num_vertices).astype(np.int32)
+    train = rng.random(g.num_vertices) < 0.3
+    owner = partition_vertices(g, 4, "metis", seed=0)
+    spec = GNNSpec(model="gat", feature_dim=16, hidden_dim=8, num_classes=5,
+                   agg_backend="pallas")
+    tr = MiniBatchTrainer.build(g, owner, 4, spec, feats, labels, train,
+                                global_batch=64, seed=3)
+    pb = tr.engine.preparer.prepare()
+    batch0 = jax.tree.map(lambda a: a[0], pb.stacked)
+    sizes = tuple(tr._layer_sizes)
+    grads = {}
+    for backend in ("scatter", "pallas"):
+        sp = dataclasses.replace(spec, agg_backend=backend)
+        with tracing() as t:
+            grads[backend] = jax.grad(
+                lambda p: minibatch_loss(sp, p, batch0, sizes, axis=None)
+            )(tr.params)
+        assert t.total("edge.softmax.reverse_pass") is None
+    assert t.total("edge.softmax.calls") == spec.num_layers
+    for x, y in zip(jax.tree.leaves(grads["pallas"]),
+                    jax.tree.leaves(grads["scatter"])):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("sync", ["dense", "halo"])
+def test_gat_reverse_pass_k4_grads_equal_k1(or_graph, node_data, sync):
+    """Each partition's edge list is its own transpose, so at k = 4 (sim)
+    every partition's backward is a reverse pass: the step's gradient
+    equals that of k = 1, leaf by leaf."""
+    feats, labels, train = node_data
+    spec = GNNSpec(model="gat", feature_dim=16, hidden_dim=8, num_classes=5,
+                   num_layers=2, agg_backend="pallas")
+    ref = FullBatchTrainer.build(
+        or_graph, np.zeros(or_graph.num_edges, np.int32), 1, spec, feats,
+        labels, train, seed=7)
+    tr = FullBatchTrainer.build(
+        or_graph, partition_edges(or_graph, 4, "hdrf", seed=1), 4, spec,
+        feats, labels, train, sync_mode=sync, mode="sim", seed=7)
+
+    def grads(t):
+        loss, _ = t._step_fns
+        return jax.grad(lambda p: jnp.mean(t._wrap(loss)(p, t.blocks)))(
+            t.params)
+
+    for a, b in zip(jax.tree.leaves(grads(tr)), jax.tree.leaves(grads(ref))):
+        assert np.abs(np.asarray(b)).max() > 0
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=1e-6)
+
+
+@pytest.mark.parametrize("sync,passes", [("local", 3), ("dense", 3),
+                                         ("halo", 3), ("ring", None)])
+def test_reverse_pass_counted_in_a_gat_step(or_graph, node_data, sync,
+                                            passes):
+    """`edge.softmax.reverse_pass` counts the backwards jax traces that
+    take the reverse pass: one a layer of GAT's full-batch step under the
+    partial-aggregate syncs, none under ring, whose chunks are not their
+    own transpose."""
+    from repro.obs.trace import tracing
+
+    feats, labels, train = node_data
+    spec = GNNSpec(model="gat", feature_dim=16, hidden_dim=8, num_classes=5,
+                   num_layers=3, agg_backend="pallas")
+    k = 1 if sync == "local" else 4
+    asg = (np.zeros(or_graph.num_edges, np.int32) if k == 1 else
+           None if sync == "ring" else
+           partition_edges(or_graph, 4, "hdrf", seed=1))
+    kw = {} if k == 1 else dict(sync_mode=sync, mode="sim")
+    tr = FullBatchTrainer.build(or_graph, asg, k, spec, feats, labels, train,
+                                **kw)
+    with tracing() as t:
+        tr.train_step()
+    assert t.total("edge.softmax.calls") > 0
+    assert t.total("edge.softmax.reverse_pass") == passes
+
+
 def test_gat_ring_moves_z_once_a_layer():
     spec = GNNSpec(model="gat", feature_dim=16, hidden_dim=8, num_classes=5,
                    num_layers=2, gat_heads=4)

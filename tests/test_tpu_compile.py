@@ -90,14 +90,14 @@ def test_segment_spmm_compiles_for_v5e(one_chip, kernel_dispatch, combiner,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# per model: the kernel call of each layer, the scopes the forward pass
+# per model: the kernel calls of each layer, the scopes the forward pass
 # keeps (`jvp(...)`) and those its backward keeps (`transpose(jvp(...))`)
 STEP_CALLS = {
-    "sage": ("segment_spmm_sum", ("edge.gather", "edge.reorder",
-                                  "edge.reduce"),
+    "sage": (("segment_spmm_sum",), ("edge.gather", "edge.reorder",
+                                     "edge.reduce"),
              ("edge.gather", "edge.reduce")),
-    "gat": ("segment_spmm_softmax", ("edge.reorder", "edge.softmax"),
-            ("edge.softmax",)),
+    "gat": (("segment_spmm_softmax", "segment_spmm_softmax_bwd"),
+            ("edge.reorder", "edge.softmax"), ("edge.softmax",)),
 }
 
 
@@ -123,16 +123,24 @@ def _fullbatch_step(model, scale, one_chip):
 
 def _kernel_calls(hlo: str) -> list:
     return sorted(re.findall(
-        r"%(segment_spmm_(?:sum|max|softmax))\.\d+ = .*?custom-call\(", hlo))
+        r"%(segment_spmm_(?:sum|max|softmax|softmax_bwd))\.\d+ = "
+        r".*?custom-call\(", hlo))
+
+
+def _softmax_backward_scatters(hlo: str) -> list:
+    """op_names of the scatters in the edge softmax's backward."""
+    return [n for n in op_names_from_hlo(hlo).values()
+            if "transpose(jvp(edge.softmax))" in n and "scatter" in n]
 
 
 @pytest.mark.parametrize("model,aggregates", [("sage", 1), ("gat", 3)])
 def test_fullbatch_step_compiles_for_v5e(one_chip, kernel_dispatch, model,
                                          aggregates):
-    """One full-batch train step: each layer makes one kernel call, which
-    the compiled step names by combiner (sage sums, GAT's edge softmax
-    computes the layer's three aggregates, row max, exp-sum and weighted
-    z, in one pass), and the edge path's scopes survive the TPU compiler's
+    """One full-batch train step: each layer makes one forward kernel
+    call, which the compiled step names by combiner (sage sums, GAT's edge
+    softmax computes the layer's three aggregates, row max, exp-sum and
+    weighted z, in one pass), GAT's backward one reverse pass a layer and
+    no scatter, and the edge path's scopes survive the TPU compiler's
     fusions."""
     from repro.gnn.models import GNNSpec
 
@@ -141,9 +149,10 @@ def test_fullbatch_step_compiles_for_v5e(one_chip, kernel_dispatch, model,
     assert [len(d) for d in spec.aggregate_dims()] == [aggregates] * 3
     _, compiled = _fullbatch_step(model, 0.01, one_chip)
     hlo = compiled.as_text()
-    call, forward, backward = STEP_CALLS[model]
-    assert hlo.count("tpu_custom_call") == spec.num_layers
-    assert _kernel_calls(hlo) == [call] * 3
+    calls, forward, backward = STEP_CALLS[model]
+    assert hlo.count("tpu_custom_call") == len(calls) * spec.num_layers
+    assert _kernel_calls(hlo) == sorted(calls * 3)
+    assert not _softmax_backward_scatters(hlo)
     names = set(op_names_from_hlo(hlo).values())
     for scope in forward:
         assert any(f"/jvp({scope})/" in n for n in names), scope
@@ -155,16 +164,22 @@ def test_gat_step_at_or_1_fits_one_v5e(one_chip, kernel_dispatch):
     """GAT's full-batch step on the sage cell's graph, OR 1.0 (25,000 V,
     1,342,278 E): the one-pass edge softmax keeps nothing per edge between
     the passes, so the step fits one chip's HBM (the three-pass softmax
-    needed 37 GB there), and no per-edge array narrower than a feature
-    tile, [E, H], is allocated."""
+    needed 37 GB there), its backward is a reverse kernel pass a layer
+    with no scatter, and no per-edge array narrower than a feature tile,
+    [E, H], is allocated."""
     g, compiled = _fullbatch_step("gat", 1.0, one_chip)
     assert g.num_edges == 1342278
     mem = compiled.memory_analysis()
     used = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes)
+    print(f"GAT step at OR 1.0: HLO temp "
+          f"{mem.temp_size_in_bytes / 2 ** 30:.2f} GiB, used "
+          f"{used / 2 ** 30:.2f} GiB")
     assert used < 15.75 * 2 ** 30, used
     hlo = compiled.as_text()
-    assert _kernel_calls(hlo) == ["segment_spmm_softmax"] * 3
+    assert _kernel_calls(hlo) == sorted(
+        ["segment_spmm_softmax", "segment_spmm_softmax_bwd"] * 3)
+    assert not _softmax_backward_scatters(hlo)
     messages = 2 * g.num_edges
     narrow = {int(e) for e in re.findall(r"f32\[(\d+),4\]", hlo)}
     assert not {e for e in narrow if e >= messages}, narrow
